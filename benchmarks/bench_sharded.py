@@ -2,9 +2,11 @@
 "Sharded execution").
 
 One long series (n = 4096 affine composes over width-192 rows) executed as a
-single scan, at 1 / 4 / 8 virtual devices.  Each device count runs in its own
-subprocess so ``--xla_force_host_platform_device_count`` is set before jax
-imports; the single-device row uses the ``vector`` backend (the dispatcher's
+single scan, at 1 / 4 / 8 virtual CPU devices.  Each device count runs in its
+own subprocess so ``--xla_force_host_platform_device_count`` is set before jax
+imports.  On a host with an accelerator the suite stops with a message: a
+child cannot reach a chip the parent holds, and CPU numbers must not pass
+for the chip's; the single-device row uses the ``vector`` backend (the dispatcher's
 honest single-device choice for a cheap batchable op), the multi-device rows
 the ``sharded`` backend (what the dispatcher picks at >= 4 devices and
 n >= 1024).
@@ -95,7 +97,7 @@ print("RESULT " + json.dumps(out))
 def _measure(dev: int, reps: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, str(dev), str(N), str(W), str(reps)],
         capture_output=True, text=True, env=env, timeout=900,
@@ -112,9 +114,19 @@ def _measure(dev: int, reps: int) -> dict:
 
 
 def run(*, smoke: bool = False) -> list:
+    import jax
+
     from repro.core.circuits import get_circuit
     from repro.core.simulator import constant_costs, simulate_distributed_scan
 
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            "bench_sharded simulates 1/4/8 devices on the CPU, one child "
+            f"process per device count; this host has a {platform}, so its "
+            "numbers would not be the devices' own.  The multi-chip path "
+            "runs on the real devices with `python chip_smoke.py --chips 4`."
+        )
     reps = 5 if smoke else 11
     rows = []
     base = _measure(1, reps)

@@ -46,6 +46,8 @@ def _run_series(frames, cache_dir, tag: str):
 def main() -> int:
     frames = _frames()
     failures = []
+    # The sessions place their plan store here, and XLA's cache too unless
+    # $JAX_COMPILATION_CACHE_DIR is set (then that directory stays the one).
     with tempfile.TemporaryDirectory(prefix="repro_cache_smoke_") as d:
         t_cold, cold = _run_series(frames, d, "cold")
         t_warm, warm = _run_series(frames, d, "warm")

@@ -7,38 +7,27 @@ Prints ``name,us_per_call,derived`` CSV rows.  Usage:
 from __future__ import annotations
 
 import argparse
+import importlib
 import sys
 import time
 import traceback
 
-from . import (
-    bench_hierarchical,
-    bench_microbench,
-    bench_operator_cost,
-    bench_registration_e2e,
-    bench_scan_kernels,
-    bench_serve,
-    bench_sharded,
-    bench_slo,
-    bench_strong_scaling,
-    bench_weak_scaling,
-    bench_work_energy,
-    roofline,
-)
-
+# Suite name -> module.  Modules are imported only when selected, so a
+# suite that starts its own processes (sharded) runs before anything in
+# this process has touched a device.
 SUITES = {
-    "microbench": bench_microbench,          # paper Fig. 8
-    "strong_scaling": bench_strong_scaling,  # paper Table 3 / Fig. 1 & 9
-    "hierarchical": bench_hierarchical,      # paper Table 4
-    "work_energy": bench_work_energy,        # paper Table 5
-    "weak_scaling": bench_weak_scaling,      # paper Fig. 10
-    "operator_cost": bench_operator_cost,    # paper Fig. 5
-    "registration_e2e": bench_registration_e2e,  # paper Figs. 1/9 (real time)
-    "scan_kernels": bench_scan_kernels,      # in-model scan paths (real time)
-    "serve": bench_serve,                    # resident runtime / sessions
-    "slo": bench_slo,                        # serving tail latency (ISSUE 8)
-    "sharded": bench_sharded,                # multi-device strong scaling
-    "roofline": roofline,                    # dry-run roofline table
+    "microbench": "bench_microbench",          # paper Fig. 8
+    "strong_scaling": "bench_strong_scaling",  # paper Table 3 / Fig. 1 & 9
+    "hierarchical": "bench_hierarchical",      # paper Table 4
+    "work_energy": "bench_work_energy",        # paper Table 5
+    "weak_scaling": "bench_weak_scaling",      # paper Fig. 10
+    "operator_cost": "bench_operator_cost",    # paper Fig. 5
+    "registration_e2e": "bench_registration_e2e",  # paper Figs. 1/9
+    "scan_kernels": "bench_scan_kernels",      # in-model scan paths
+    "serve": "bench_serve",                    # resident runtime / sessions
+    "slo": "bench_slo",                        # serving tail latency
+    "sharded": "bench_sharded",                # multi-device strong scaling
+    "roofline": "roofline",                    # dry-run roofline table
 }
 
 
@@ -47,13 +36,16 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     names = list(SUITES) if not args.only else args.only.split(",")
     print("name,us_per_call,derived")
     failed = []
     for name in names:
-        mod = SUITES[name]
         t0 = time.time()
         try:
+            mod = importlib.import_module(f".{SUITES[name]}", __package__)
             rows = mod.run()
         except Exception:  # noqa: BLE001 — isolate suite failures
             traceback.print_exc()

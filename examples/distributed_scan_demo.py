@@ -21,7 +21,7 @@ from functools import partial  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from repro.core.deformation import compose_batched  # noqa: E402
@@ -30,7 +30,9 @@ from repro.core.distributed import (  # noqa: E402
     distributed_blocked_scan,
     hierarchical_collective_scan,
 )
+from repro.runtime.compile_cache import enable_persistent_cache  # noqa: E402
 
+enable_persistent_cache()
 devs = np.array(jax.devices())
 print(f"devices: {len(devs)} (virtual pod layout 2x4)")
 

@@ -14,6 +14,9 @@ from repro.core.deformation import compose_batched
 from repro.core.engine import available_backends, cache_stats, dispatch, scan
 from repro.core.scan import blocked_scan, prefix_scan
 from repro.core.work_stealing import static_reduce, stealing_reduce
+from repro.runtime.compile_cache import enable_persistent_cache
+
+enable_persistent_cache()
 
 # ---------------------------------------------------------------- circuits
 print("== Prefix circuits (paper Table 1) ==")

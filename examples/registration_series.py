@@ -15,9 +15,11 @@ import numpy as np
 import repro
 from repro.core.registration import SeriesRegistrar
 from repro.data.images import make_series, stream_series
+from repro.runtime.compile_cache import enable_persistent_cache
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=24)
     ap.add_argument("--backend", default=None,

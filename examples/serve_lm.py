@@ -8,9 +8,11 @@ import argparse
 import numpy as np
 
 from repro.launch.serve import Request, ServeConfig, Server
+from repro.runtime.compile_cache import enable_persistent_cache
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="zamba2-7b",
                     help="any of the 10 assigned archs (reduced config)")

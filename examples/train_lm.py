@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.launch.train import TrainConfig, train
 from repro.models.config import ArchConfig
+from repro.runtime.compile_cache import enable_persistent_cache
 
 # ~100M params: embed 2*32k*512 = 33M + 16 blocks ~ 4M = ~97M.
 ARCH_100M = ArchConfig(
@@ -32,6 +33,7 @@ ARCH_100M = ArchConfig(
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=4)

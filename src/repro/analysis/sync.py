@@ -29,6 +29,7 @@ arguments are never even inspected).
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 from collections import Counter
@@ -52,6 +53,19 @@ _checking: bool = os.environ.get(_ENV_FLAG, "").strip() not in ("", "0", "false"
 
 _observed: Counter = Counter()
 _observed_lock = threading.Lock()
+
+# Sanitizer thread ids.  Not threading.get_ident(): CPython reuses an ident
+# once its thread has exited, which would merge two short-lived, unordered
+# threads into one program order and hide their race.
+_thread_ids = itertools.count()
+_thread_local = threading.local()
+
+
+def _thread_id() -> int:
+    tid = getattr(_thread_local, "tid", None)
+    if tid is None:
+        tid = _thread_local.tid = next(_thread_ids)
+    return tid
 
 
 def invariants_enabled() -> bool:
@@ -114,7 +128,7 @@ def sync_point(
     if kind is None:
         return
     tracker = get_race_tracker()
-    tid = threading.get_ident()
+    tid = _thread_id()
     if kind in ("read", "write"):
         if var is None:
             raise ValueError(f"sync_point({label!r}, {kind!r}) requires var=")

@@ -24,6 +24,11 @@ import jax.numpy as jnp
 
 Deformation = Dict[str, jax.Array]
 
+#: Products of rotations with pixel coordinates in full float32.  A TPU
+#: multiplies float32 matrices in one bfloat16 pass by default, whose 8-bit
+#: mantissa puts a coordinate near 1000 px off by up to 2 px.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def identity_deformation(dtype=jnp.float32) -> Deformation:
     return {"angle": jnp.zeros((), dtype), "shift": jnp.zeros((2,), dtype)}
@@ -51,9 +56,10 @@ def compose(a: Deformation, b: Deformation) -> Deformation:
     angle = a["angle"] + b["angle"]
     rb = rotation_matrix(b["angle"])  # (..., 2, 2) when batched
     if a["shift"].ndim == 1:
-        shift = rb @ a["shift"] + b["shift"]
+        shift = jnp.matmul(rb, a["shift"], precision=_EXACT) + b["shift"]
     else:
-        shift = jnp.einsum("ij...,...j->...i", rb, a["shift"]) + b["shift"]
+        shift = jnp.einsum("ij...,...j->...i", rb, a["shift"],
+                           precision=_EXACT) + b["shift"]
     return {"angle": angle, "shift": shift}
 
 
@@ -80,43 +86,61 @@ def inverse(d: Deformation) -> Deformation:
     """phi^{-1}: R(-a)(x - c - G) + c."""
     ang = -d["angle"]
     r = rotation_matrix(ang)
-    return {"angle": ang, "shift": -(r @ d["shift"])}
+    return {"angle": ang, "shift": -jnp.matmul(r, d["shift"], precision=_EXACT)}
 
 
-def _bilinear_sample(img: jax.Array, coords: jax.Array) -> jax.Array:
-    """Sample img[H, W] at float coords[..., 2] (row, col), edge-clamped."""
+def _bilinear_sample(img: jax.Array, coords: jax.Array, kernel: bool = False):
+    """Sample img[H, W] at float coords[..., 2] (row, col), edge-clamped.
+
+    ``kernel``: fetch the four neighbours with the Pallas kernel
+    (``kernels/bilinear_fetch.py``) instead of four gathers.
+    """
     h, w = img.shape
     r = jnp.clip(coords[..., 0], 0.0, h - 1.0)
     c = jnp.clip(coords[..., 1], 0.0, w - 1.0)
     r0 = jnp.floor(r).astype(jnp.int32)
     c0 = jnp.floor(c).astype(jnp.int32)
-    r1 = jnp.minimum(r0 + 1, h - 1)
-    c1 = jnp.minimum(c0 + 1, w - 1)
     fr = r - r0
     fc = c - c0
-    v00 = img[r0, c0]
-    v01 = img[r0, c1]
-    v10 = img[r1, c0]
-    v11 = img[r1, c1]
+    if kernel:
+        from repro.kernels.bilinear_fetch import bilinear_fetch
+
+        v00, v01, v10, v11 = bilinear_fetch(img, r0, c0)
+    else:
+        r1 = jnp.minimum(r0 + 1, h - 1)
+        c1 = jnp.minimum(c0 + 1, w - 1)
+        v00 = img[r0, c0]
+        v01 = img[r0, c1]
+        v10 = img[r1, c0]
+        v11 = img[r1, c1]
     top = v00 * (1 - fc) + v01 * fc
     bot = v10 * (1 - fc) + v11 * fc
     return top * (1 - fr) + bot * fr
 
 
-def warp(img: jax.Array, d: Deformation) -> jax.Array:
+def warp(img: jax.Array, d: Deformation, *, kernel: bool | None = None):
     """(T o phi)(x) = T(phi(x)): deform template ``img`` by ``d``.
 
-    Differentiable w.r.t. ``d`` (bilinear interpolation).
+    Differentiable w.r.t. ``d`` (bilinear interpolation).  ``kernel=None``
+    fetches the neighbours with the Pallas kernel on a TPU (per-pixel
+    gathers cost a memory tile each there) when the image fits its VMEM,
+    with gathers elsewhere; ``True`` forces the kernel (interpreted off the
+    TPU), ``False`` the gathers.  Both give the same values.
     """
     h, w = img.shape
+    if kernel is None:
+        from repro.kernels.bilinear_fetch import fits
+
+        kernel = jax.default_backend() == "tpu" and fits((h, w))
     ctr = jnp.array([(h - 1) / 2.0, (w - 1) / 2.0])
     rows = jnp.arange(h, dtype=jnp.float32)
     cols = jnp.arange(w, dtype=jnp.float32)
     grid = jnp.stack(jnp.meshgrid(rows, cols, indexing="ij"), axis=-1)  # (H,W,2)
     rel = grid - ctr
     rot = rotation_matrix(d["angle"])
-    coords = jnp.einsum("ij,hwj->hwi", rot, rel) + ctr + d["shift"]
-    return _bilinear_sample(img, coords)
+    coords = jnp.einsum("ij,hwj->hwi", rot, rel, precision=_EXACT)
+    coords = coords + ctr + d["shift"]
+    return _bilinear_sample(img, coords, kernel)
 
 
 def ncc(a: jax.Array, b: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -41,14 +41,7 @@ Op = Callable[[Any, Any], Any]
 def _axis_size(axis_name: str, axis_size: Optional[int]) -> int:
     if axis_size is not None:
         return int(axis_size)
-    fn = getattr(jax.lax, "axis_size", None)  # static inside shard_map
-    if fn is None:
-        raise ValueError(
-            f"cannot determine the size of mesh axis {axis_name!r}: this jax "
-            f"({jax.__version__}) has no jax.lax.axis_size — pass the static "
-            f"axis_size= argument explicitly"
-        )
-    return int(fn(axis_name))
+    return int(jax.lax.axis_size(axis_name))  # static inside shard_map
 
 
 def _where_tree(mask, a, b):

@@ -34,6 +34,7 @@ the inclusive prefix scan in the same container type.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, List, Optional, Sequence
 
 from .backends import (
@@ -80,6 +81,10 @@ from . import sharded as _sharded  # noqa: F401
 
 Op = Callable[[Any, Any], Any]
 
+#: The backend decision of the most recent :func:`scan` that ran one
+#: (cost-model dispatch, or the caller's named backend).
+last_dispatch: Optional[Dispatch] = None
+
 __all__ = [
     "CHEAP_OP_COST",
     "CROSS_STEAL_MIN_IMBALANCE",
@@ -103,6 +108,7 @@ __all__ = [
     "lower_collective",
     "dispatch",
     "Dispatch",
+    "last_dispatch",
     "measure_op_cost",
     "plan_cache",
     "lowered_cache",
@@ -118,12 +124,12 @@ __all__ = [
 
 
 def _accel_available() -> bool:
-    """True when a real accelerator backs the default jax device — the
-    regime where the interpreted-on-CPU Pallas kernels become compiled
-    Mosaic kernels and the decoupled backend earns its keep."""
-    import jax
+    """True when a TPU backs the default jax device — the regime where the
+    Pallas kernels compile instead of running interpreted, and the
+    decoupled backend earns its keep."""
+    from repro.kernels._tiling import resolve_interpret
 
-    return jax.default_backend() in ("tpu", "gpu")
+    return not resolve_interpret(None)
 
 
 def cache_stats():
@@ -340,7 +346,8 @@ def _scan_impl(
         # Fair-share sizing: concurrent tenants on the shared pool divide
         # the machine instead of each planning a full-size thread army.
         workers = pool_aware_workers(pool, workers)
-    if backend is None:
+    dispatched = backend is None
+    if dispatched:
         cost = op_cost
         if cost is None:
             # Telemetry feedback: operator adapters expose a running per-call
@@ -394,6 +401,11 @@ def _scan_impl(
     algorithm = algorithm or "ladner_fischer"
     strategy = strategy or "reduce_then_scan"
     fn = get_backend(backend)
+    global last_dispatch
+    last_dispatch = (
+        dataclasses.replace(d, backend=backend) if dispatched
+        else Dispatch(backend, algorithm, reason="backend named by caller")
+    )
 
     # --- single-pass decoupled lookback: no plan, no global phase.
     if backend == "decoupled":

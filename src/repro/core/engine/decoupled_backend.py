@@ -53,10 +53,6 @@ from .backends import register_backend
 Op = Callable[[Any, Any], Any]
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def stack_elements(xs):
     """Stack a list of same-structure pytree elements along a new leading
     axis, or return None when the elements are not stackable (mismatched
@@ -91,9 +87,6 @@ def exec_decoupled(
     **_,
 ) -> Tuple[Any, Any]:
     """Single-pass decoupled-lookback scan; returns ``(ys, total)``."""
-    if interpret is None:
-        interpret = _auto_interpret()
-
     if isinstance(xs, list):
         stacked = stack_elements(xs)
         if stacked is None:
@@ -129,7 +122,8 @@ def exec_decoupled(
                 [seed_row, jnp.zeros((1,), x2.dtype)], axis=0
             )
 
-    t = num_blocks if num_blocks is not None else default_num_tiles(n)
+    t = (num_blocks if num_blocks is not None
+         else default_num_tiles(n, x2.shape[1] * x2.dtype.itemsize))
     t = max(1, min(int(t), n))
     x2p, _ = pad_rows(x2, t)
 

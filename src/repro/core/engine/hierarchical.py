@@ -20,9 +20,8 @@ Two domains, same phase structure:
   the paper's MPI-nodes × OpenMP-threads deployment.
 * **array** (pytree of arrays, vectorizable operator): phase 1/3 are
   vectorized segment scans/applies (``vmap`` + broadcast combine), routed
-  through the fused Pallas tile kernels (``kernels/tile_scan.py``) when the
-  input is a single float leaf — eligible exactly where the ``pallas`` tiles
-  backend is.
+  through the fused Pallas tile kernels (``kernels/tile_scan.py``) on a TPU
+  when every leaf is a float (leaves are packed into one array).
 
 ``last_stats`` (a :class:`HierStats`) records per-phase wall time, segment
 boundaries and per-segment steal statistics for the most recent element
@@ -384,8 +383,43 @@ def _pallas_eligible(xs) -> bool:
     import jax
     import jax.numpy as jnp
 
-    leaves = jax.tree.leaves(xs)
-    return len(leaves) == 1 and jnp.issubdtype(leaves[0].dtype, jnp.floating)
+    return all(
+        jnp.issubdtype(t.dtype, jnp.floating) for t in jax.tree.leaves(xs)
+    )
+
+
+def _exec_hier_tiles(op: Op, xs, s: int, *, interpret: Optional[bool]):
+    """Phases 1 and 3 as the fused Pallas tile kernels.
+
+    Leaves are packed column-wise into one (n, D) array (bit-exact, see
+    ``_tiling.packed_op``).  A grid step holds one whole tile, so the
+    ``s`` segments are cut into as many tiles (at least ``s``) as it takes
+    for each tile's block to fit VMEM; the cross-tile phase is the vector
+    executor over the tile totals.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels._tiling import (
+        pack_leaves,
+        packed_op,
+        pad_rows,
+        unpack_leaves,
+        vmem_tiles,
+    )
+    from repro.kernels.tile_scan import tile_apply, tile_local_scan
+
+    x2, spec = pack_leaves(xs)
+    n, d = x2.shape
+    pop = packed_op(op, spec)
+    t = vmem_tiles(n, d * x2.dtype.itemsize, s)
+    x2p, _ = pad_rows(x2, t)
+    local, partials = tile_local_scan(pop, x2p, t, interpret=interpret)
+    gscan, _ = exec_vector(pop, get_plan("ladner_fischer", t), partials[:, 0])
+    seeds = jnp.concatenate([partials[:1, 0], gscan[:-1]], axis=0)
+    y2 = tile_apply(pop, local, seeds[:, None], interpret=interpret)[:n]
+    ys = unpack_leaves(y2, spec)
+    return ys, jax.tree.map(lambda t: t[-1], ys)
 
 
 def _exec_hier_array(
@@ -399,6 +433,8 @@ def _exec_hier_array(
 ) -> Tuple[Any, Any]:
     import jax
     import jax.numpy as jnp
+
+    from repro.kernels._tiling import resolve_interpret
 
     from ..scan import _local_inclusive_scan
 
@@ -416,24 +452,9 @@ def _exec_hier_array(
         return ys, jax.tree.map(lambda t: t[-1], ys)
 
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not resolve_interpret(None)
     if use_pallas and _pallas_eligible(xs):
-        # Tile-local fused kernels: per-tile scan + seed apply (tiles mode).
-        from repro.kernels.tile_scan import tile_apply, tile_local_scan
-
-        leaf = jax.tree.leaves(xs)[0]
-        tail = leaf.shape[1:]
-        x2 = leaf.reshape(n, -1)
-        itp = interpret if interpret is not None else (
-            jax.default_backend() != "tpu"
-        )
-        local, partials = tile_local_scan(op, x2, s, interpret=itp)
-        gscan, _ = exec_vector(op, plan, partials)
-        seeds = jnp.concatenate([partials[:1], gscan[:-1]], axis=0)
-        out2 = tile_apply(op, local, seeds, interpret=itp)
-        ys = out2.reshape((n,) + tail)
-        total = gscan[-1].reshape(tail)
-        return jax.tree.unflatten(jax.tree.structure(xs), [ys]), total
+        return _exec_hier_tiles(op, xs, s, interpret=interpret)
 
     k = n // s
     segs = jax.tree.map(lambda t: t.reshape((s, k) + t.shape[1:]), xs)

@@ -12,8 +12,8 @@ as the ``blocked`` backend):
 
 Restricted to single-leaf float arrays and operators that vectorize over the
 leading axis (the "common low-compute operators" regime of the paper §4.1).
-On CPU the kernels run in interpret mode (``interpret=None`` auto-detects);
-on TPU the same bodies compile via Mosaic.
+``interpret=None`` compiles the kernels on a TPU and interprets them
+anywhere else (``kernels/_tiling.resolve_interpret``).
 """
 
 from __future__ import annotations
@@ -33,10 +33,6 @@ from .backends import (
 from .plan import ExecutionPlan
 
 Op = Callable[[Any, Any], Any]
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _as_2d(xs) -> Tuple[jax.Array, Tuple[int, ...]]:
@@ -87,8 +83,6 @@ def exec_pallas(
 ) -> Tuple[Any, Any]:
     from repro.kernels.tile_scan import fused_round, tile_apply, tile_local_scan
 
-    if interpret is None:
-        interpret = _auto_interpret()
     y2, tail = _as_2d(xs)
     n = y2.shape[0]
 
@@ -107,9 +101,9 @@ def exec_pallas(
     if n % t:
         raise ValueError(f"n={n} not divisible by tile count {t}")
     local, partials = tile_local_scan(op, y2, t, interpret=interpret)
-    gscan, _ = exec_vector(op, plan, partials)
-    seeds = jnp.concatenate([partials[:1], gscan[:-1]], axis=0)
-    out = tile_apply(op, local, seeds, interpret=interpret)
+    gscan, _ = exec_vector(op, plan, partials[:, 0])
+    seeds = jnp.concatenate([partials[:1, 0], gscan[:-1]], axis=0)
+    out = tile_apply(op, local, seeds[:, None], interpret=interpret)
     return out.reshape((n,) + tail), None
 
 

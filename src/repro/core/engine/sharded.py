@@ -396,7 +396,6 @@ _fn_cache_lock = threading.Lock()
 def _get_sharded_fn(op, spec, mesh, k, halo, blocks, width, dtype, stealing):
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.kernels._tiling import lift_masked, packed_op
 
@@ -415,9 +414,9 @@ def _get_sharded_fn(op, spec, mesh, k, halo, blocks, width, dtype, stealing):
     pop = lift_masked(packed_op(op, spec))
     body = _build_sharded_fn(pop, devices, k, halo, blocks, width, dtype,
                              slot, stealing)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P(AXIS),
-        check_rep=False,
+        check_vma=False,
     ))
     entry = (fn, slot)
     if key is not None:

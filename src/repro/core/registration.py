@@ -42,9 +42,31 @@ class RegistrationConfig:
     levels: int = 2              # pyramid depth
     max_iters: int = 300         # per level
     lr_shift: float = 1.0        # gradient step for translation (pixels)
-    lr_angle: float = 5e-4       # gradient step for rotation (radians)
+    lr_angle: float = 5e-4       # gradient step for rotation (radians) on
+                                 # frames up to 96 x 96; see _angle_step
     tol: float = 1e-7            # stop when |Delta D| < tol
     estimate_rotation: bool = True
+
+
+#: Mean squared distance of a pixel from the centre of a 96 x 96 frame, the
+#: size ``lr_angle`` was tuned on.
+_ANGLE_REF_R2 = 1536.0
+
+
+def _angle_step(cfg: RegistrationConfig, shape: Tuple[int, int]) -> float:
+    """Rotation step for one pyramid level of ``shape``.
+
+    A rotation moves a pixel in proportion to its distance from the centre,
+    so the curvature of the distance in the angle grows with the mean
+    squared distance r2.  A fixed step overshoots on larger frames: at
+    960 x 928 the minimiser oscillates until ``max_iters``.  The step
+    shrinks as 1 / r2 beyond the tuned size (a Gauss-Newton scaling).
+    """
+    if not cfg.estimate_rotation:
+        return 0.0
+    h, w = shape
+    r2 = (h * h + w * w - 2) / 12.0
+    return cfg.lr_angle * min(1.0, _ANGLE_REF_R2 / r2)
 
 
 class RegResult(NamedTuple):
@@ -73,6 +95,7 @@ def _minimize_level(
 
     loss = lambda d: ncc_distance(ref, tmpl, d)
     grad = jax.grad(loss)
+    ang_step = _angle_step(cfg, ref.shape)
 
     def active_of(state):
         _, prev, cur, it = state
@@ -82,7 +105,6 @@ def _minimize_level(
         d, prev, cur, it = state
         act = active_of(state)
         g = grad(d)
-        ang_step = cfg.lr_angle if cfg.estimate_rotation else 0.0
         d_new = {
             "angle": d["angle"] - ang_step * g["angle"],
             "shift": d["shift"] - cfg.lr_shift * g["shift"],
@@ -110,16 +132,24 @@ def _pyramid(img: jax.Array, levels: int):
     return pyr[::-1]  # coarse -> fine
 
 
-@partial(jax.jit, static_argnames=("cfg",))
 def register_pair(
     ref: jax.Array,
     tmpl: jax.Array,
     init: Optional[Deformation] = None,
     cfg: RegistrationConfig = RegistrationConfig(),
 ) -> RegResult:
-    """Function A: estimate phi with f_tmpl o phi ~= f_ref (multilevel)."""
+    """Function A: estimate phi with f_tmpl o phi ~= f_ref (multilevel).
+
+    ``init=None`` starts from the identity, passed in as an argument: a
+    start known at trace time would fold into a second compiled program.
+    """
     if init is None:
         init = identity_deformation()
+    return _register_pair(ref, tmpl, init, cfg)
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _register_pair(ref, tmpl, init, cfg) -> RegResult:
     refs = _pyramid(ref, cfg.levels)
     tmps = _pyramid(tmpl, cfg.levels)
     scale = 2.0 ** (cfg.levels - 1)
@@ -234,12 +264,12 @@ def fused_ncc_distance(
     through HBM.  Equivalent to :func:`~repro.core.deformation.ncc_distance`
     up to fp accumulation order.
     """
+    from repro.kernels._tiling import resolve_interpret
     from repro.kernels.warp_ncc import warp_ncc
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     _, corr = warp_ncc(
-        tmpl, ref, d["angle"], d["shift"], tile=tile, interpret=interpret
+        tmpl, ref, d["angle"], d["shift"], tile=tile,
+        interpret=resolve_interpret(interpret),
     )
     return 1.0 - corr
 
@@ -260,12 +290,13 @@ class RegistrationOperator:
       :class:`~repro.core.engine.telemetry.OpTelemetry`; the adapter exposes
       ``op_cost_estimate`` so the dispatcher routes the *next* call from
       observed costs (data-dependent iteration counts drift over a series).
-    * **fused guess check** — when ``skip_tol`` is set, the composed initial
+    * **guess check** — when ``skip_tol`` is set, the composed initial
       guess phi_{j,k} o phi_{i,j} is scored first and refinement is skipped
-      when it already registers within tolerance.  The warp+NCC evaluation
-      is the hot path; it routes through the fused Pallas kernel
-      (``kernels/warp_ncc.py``) where eligible (tile-divisible frames;
-      on-TPU by default, ``fused=True`` forces interpret mode elsewhere).
+      when it already registers within tolerance.  ``fused=True`` scores it
+      through the fused warp+NCC Pallas kernel (``kernels/warp_ncc.py``,
+      tile-divisible frames), interpreted: the kernel does not lower for the
+      TPU (its flat gather is refused), so it is never on by default and
+      asking for it on a TPU raises here rather than mid-scan.
 
     Thread-safe — the work-stealing executors apply it concurrently.
     """
@@ -309,12 +340,13 @@ class RegistrationOperator:
         self.skip_tol = skip_tol
         self.tile = tile
         h, w = registrar.frames.shape[1:]
-        if fused is None:
-            fused = (
-                jax.default_backend() == "tpu"
-                and fused_ncc_eligible((h, w), tile)
+        if fused and jax.default_backend() == "tpu":
+            raise ValueError(
+                "fused=True needs the warp_ncc Pallas kernel, which does not "
+                "lower for the TPU: Mosaic refuses its flat jnp.take gather "
+                "(only 2-D gathers are supported). Leave fused unset."
             )
-        self.fused = fused and fused_ncc_eligible((h, w), tile)
+        self.fused = bool(fused) and fused_ncc_eligible((h, w), tile)
         self.skipped = 0
         self.refined = 0
         self._count_lock = threading.Lock()

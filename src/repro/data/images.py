@@ -12,16 +12,27 @@ registration problem hard and the scan operator imbalanced:
 
 from __future__ import annotations
 
-from typing import Iterator, Tuple
+from typing import Iterator, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.deformation import Deformation, make_deformation, warp
 
+#: Frame size: ``n`` for n x n frames, or ``(H, W)`` (the paper's frames
+#: are 1920 x 1856).
+Size = Union[int, Tuple[int, int]]
+
+
+def _hw(size: Size) -> Tuple[int, int]:
+    if isinstance(size, int):
+        return size, size
+    h, w = size
+    return int(h), int(w)
+
 
 def lattice_image(
-    size: int = 96,
+    size: Size = 96,
     period: float = 12.0,
     key: jax.Array | None = None,
     distortion: float = 0.15,
@@ -30,8 +41,11 @@ def lattice_image(
     distortion field (the 'deviations' that carry the material signal)."""
     if key is None:
         key = jax.random.PRNGKey(1410)
-    r = jnp.arange(size, dtype=jnp.float32)
-    y, x = jnp.meshgrid(r, r, indexing="ij")
+    h, w = _hw(size)
+    y, x = jnp.meshgrid(
+        jnp.arange(h, dtype=jnp.float32), jnp.arange(w, dtype=jnp.float32),
+        indexing="ij",
+    )
     img = (
         jnp.cos(2 * jnp.pi * x / period)
         + jnp.cos(2 * jnp.pi * y / period)
@@ -40,8 +54,8 @@ def lattice_image(
     k1, k2 = jax.random.split(key)
     # Low-frequency defects: a few Gaussian blobs that break perfect symmetry.
     nblobs = 6
-    cx = jax.random.uniform(k1, (nblobs,)) * size
-    cy = jax.random.uniform(k2, (nblobs,)) * size
+    cx = jax.random.uniform(k1, (nblobs,)) * w
+    cy = jax.random.uniform(k2, (nblobs,)) * h
     for i in range(nblobs):
         img = img + distortion * jnp.exp(
             -(((x - cx[i]) ** 2 + (y - cy[i]) ** 2) / (2 * (period * 0.8) ** 2))
@@ -50,10 +64,19 @@ def lattice_image(
     return img
 
 
+@jax.jit
+def _render_frame(base, shift, rot, nkey, noise):
+    # f_i(x) = f_0(phi^{-1}(x)) so that f_i(phi(x)) = f_0(x):
+    # warp() samples f_0 at phi_inv(x) when given the inverse deformation.
+    inv = make_deformation(-rot, -shift)  # small-angle inverse approx.
+    frame = warp(base, inv)
+    return frame + noise * jax.random.normal(nkey, frame.shape)
+
+
 def make_series(
     key: jax.Array,
     n_frames: int,
-    size: int = 96,
+    size: Size = 96,
     period: float = 12.0,
     drift_step: float | None = None,
     rotation_step: float = 0.002,
@@ -78,7 +101,7 @@ def stream_series(
     n_frames: int,
     *,
     chunk_size: int = 32,
-    size: int = 96,
+    size: Size = 96,
     period: float = 12.0,
     drift_step: float | None = None,
     rotation_step: float = 0.002,
@@ -91,8 +114,10 @@ def stream_series(
     trajectory is fixed up front (it is metadata-sized), but frames are
     *rendered* lazily per chunk, so a consumer — ``repro.register_series`` —
     can overlap function-A preprocessing with acquisition instead of waiting
-    for the full series.  ``make_series`` is the single-chunk special case,
-    so both produce identical frames for the same arguments.
+    for the full series.  Every frame is rendered by the same one-frame
+    program, so frames are bit-identical whatever the chunk size (a batched
+    render of a different batch size rounds differently); ``make_series``
+    is the single-chunk special case.
 
     Returns ``(chunks, true)``: the chunk iterator and the ground-truth
     cumulative deformations (for evaluation only — not consumed upstream).
@@ -113,19 +138,13 @@ def stream_series(
     cum_rot = jnp.cumsum(rots)
     nkeys = jax.random.split(kn, n_frames)
 
-    def render(shift, rot, nkey):
-        # f_i(x) = f_0(phi^{-1}(x)) so that f_i(phi(x)) = f_0(x):
-        # warp() samples f_0 at phi_inv(x) when given the inverse deformation.
-        inv = make_deformation(-rot, -shift)  # small-angle inverse approx.
-        frame = warp(base, inv)
-        return frame + noise * jax.random.normal(nkey, frame.shape)
-
-    render_chunk = jax.vmap(render)
-
     def chunks() -> Iterator[jax.Array]:
         for lo in range(0, n_frames, chunk_size):
             hi = min(lo + chunk_size, n_frames)
-            yield render_chunk(cum_shift[lo:hi], cum_rot[lo:hi], nkeys[lo:hi])
+            yield jnp.stack([
+                _render_frame(base, cum_shift[i], cum_rot[i], nkeys[i], noise)
+                for i in range(lo, hi)
+            ])
 
     true = {"angle": cum_rot, "shift": cum_shift}
     return chunks(), true

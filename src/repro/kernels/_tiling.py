@@ -69,21 +69,71 @@ def build_round_matrices(rnd, n: int):
 
 
 # ---------------------------------------------------------------------------
-# tile sizing + padding
+# platform + in-kernel scan
 # ---------------------------------------------------------------------------
 
 
-def default_num_tiles(n: int) -> int:
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``interpret=None`` means: compile for a TPU, interpret anywhere else.
+
+    Mosaic compiles Pallas kernels for the TPU only; elsewhere (CPU tests)
+    the same bodies run in interpret mode.  The one place that rule lives.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
+def block_scan(op: Op, x):
+    """Inclusive scan of a (K, d) block along rows, inside a Pallas kernel.
+
+    Hillis–Steele: log2(K) full-block steps, each combining every row with
+    the row ``s`` above it (a sublane rotation, masked where ``i < s``).
+    ``lax.associative_scan`` does not lower on the TPU (its strided
+    slices are refused), this does; ``op`` must act row-wise on (K, d).
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < k:
+        x = jnp.where(row >= s, op(pltpu.roll(x, s, 0), x), x)
+        s *= 2
+    return x
+
+
+# ---------------------------------------------------------------------------
+# tile sizing + padding
+# ---------------------------------------------------------------------------
+
+#: Largest (rows, d) block one grid step of a scan kernel holds.  The TPU
+#: compiler refuses a block scan of 4 MiB blocks against the 16 MiB scoped
+#: VMEM of a v5e (in and out blocks are double-buffered, plus the scan's
+#: temporaries); 1 MiB leaves room for the lookback boards.
+TILE_BLOCK_BYTES = 1 << 20
+
+
+def vmem_tiles(n: int, row_bytes: int, at_least: int = 1) -> int:
+    """Fewest tiles (>= ``at_least``) whose blocks fit ``TILE_BLOCK_BYTES``."""
+    need = -(-n * max(int(row_bytes), 1) // TILE_BLOCK_BYTES)
+    return max(1, int(at_least), min(need, n))
+
+
+def default_num_tiles(n: int, row_bytes: int = 4) -> int:
     """Tile count for an n-element single-pass scan.
 
     Small inputs run as one tile (the lookback machinery is pure overhead
-    below ~2 tiles); large inputs cap at 16 tiles so the sequential-grid
-    interpreter loop stays short on CPU while each tile still holds enough
-    rows to vectorize.
+    below ~2 tiles); otherwise 16 tiles, or as many more as it takes for
+    each tile's block to fit ``TILE_BLOCK_BYTES`` of VMEM.
     """
     if n < 32:
         return 1
-    return max(1, min(16, n // 16))
+    return vmem_tiles(n, row_bytes, max(1, min(16, n // 16)))
 
 
 def pad_rows(x2, num_tiles: int):

@@ -42,6 +42,8 @@ from jax.experimental import pallas as pl
 from repro.analysis.invariants import check_board_published, check_lookback_step
 from repro.analysis.sync import invariants_enabled, sync_point
 
+from ._tiling import block_scan, resolve_interpret
+
 Op = Callable[[jax.Array, jax.Array], jax.Array]
 
 #: Tile-status protocol flags (published in program order).
@@ -102,15 +104,14 @@ def lookback_scan(
 
     ``op`` must be batched over the leading axis (it is applied to (m, d)
     row blocks).  ``n`` must divide ``num_tiles`` (see
-    ``_tiling.pad_rows``).  ``seed``: optional (d,) or (1, d) exclusive
-    prefix of the whole scan.
+    ``_tiling.pad_rows``); each grid step holds one whole tile, so size
+    tiles with ``_tiling.vmem_tiles``.  ``seed``: optional (d,) or (1, d)
+    exclusive prefix of the whole scan.
 
     Returns ``(y, status, aggs, prefs)``: the (n, d) inclusive scan plus
     the published per-tile protocol state ((t, 1) int32 statuses, (t, d)
     aggregates, (t, d) inclusive prefixes) for inspection/testing.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, d = x.shape
     t = int(num_tiles)
     if t < 1:
@@ -134,20 +135,18 @@ def lookback_scan(
         def _init():
             status_ref[...] = jnp.zeros_like(status_ref)
 
-        seg = x_ref[0]                                        # (K, d)
-        local = jax.lax.associative_scan(op, seg, axis=0)
-        agg = local[k - 1][None]                              # (1, d)
-        pl.store(agg_ref, (pl.ds(i, 1), slice(None)), agg)
-        pl.store(status_ref, (pl.ds(i, 1), slice(None)),
-                 jnp.full((1, 1), FLAG_AGG, jnp.int32))
+        local = block_scan(op, x_ref[0])                      # (K, d)
+        agg = local[k - 1:]                                   # (1, d)
+        agg_ref[pl.ds(i, 1), :] = agg
+        status_ref[pl.ds(i, 1), :] = jnp.full((1, 1), FLAG_AGG, jnp.int32)
 
         def resolve(_):
             # Walk back over predecessors: accumulate AGG aggregates,
             # fold in the first PREFIX and stop (lookback_resolve twin).
             def read(j):
-                st = pl.load(status_ref, (pl.ds(j, 1), slice(None)))[0, 0]
-                a = pl.load(agg_ref, (pl.ds(j, 1), slice(None)))
-                p = pl.load(pref_ref, (pl.ds(j, 1), slice(None)))
+                st = status_ref[pl.ds(j, 1), :][0, 0]
+                a = agg_ref[pl.ds(j, 1), :]
+                p = pref_ref[pl.ds(j, 1), :]
                 return st, jnp.where(st == FLAG_PREFIX, p, a)
 
             st0, v0 = read(i - 1)
@@ -178,9 +177,8 @@ def lookback_scan(
             )
             incl = jnp.where(i == 0, agg, op(excl, agg))
         y_ref[0] = out
-        pl.store(pref_ref, (pl.ds(i, 1), slice(None)), incl)
-        pl.store(status_ref, (pl.ds(i, 1), slice(None)),
-                 jnp.full((1, 1), FLAG_PREFIX, jnp.int32))
+        pref_ref[pl.ds(i, 1), :] = incl
+        status_ref[pl.ds(i, 1), :] = jnp.full((1, 1), FLAG_PREFIX, jnp.int32)
 
     def blk(*shape):
         return pl.BlockSpec((1,) + shape, lambda i: (i,) + (0,) * len(shape))
@@ -199,7 +197,7 @@ def lookback_scan(
             jax.ShapeDtypeStruct((t, d), x.dtype),
             jax.ShapeDtypeStruct((t, d), x.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x3, seed_row)
     if invariants_enabled():
         # Terminal board state (debug runs only — forces a device sync):

@@ -17,20 +17,20 @@ both driven by a precompiled :class:`repro.core.engine.plan.ExecutionPlan`:
 2. **Tile kernels** (``tile_local_scan`` / ``tile_apply``): the paper's
    local–global–local decomposition (§4.1) with the two local phases fused
    into one kernel launch each.  ``tile_local_scan`` computes per-tile
-   inclusive scans (``lax.associative_scan`` on the VPU) plus tile totals;
+   inclusive scans (``_tiling.block_scan`` on the VPU) plus tile totals;
    the tiny global phase over tile totals runs outside (the engine's vector
    executor on the plan); ``tile_apply`` folds each tile's exclusive global
    prefix back in with a single batched operator application.
 
-On this container's CPU the kernels run with ``interpret=True`` (the repo's
-``pallas_interpret`` idiom — see ``kernels/ops.py``); on TPU the same bodies
-compile via Mosaic.  Feature dims should be padded to the 128-lane width for
-peak MXU utilization; correctness does not depend on it.
+``interpret=None`` compiles the kernels via Mosaic on a TPU and interprets
+them anywhere else (``_tiling.resolve_interpret``).  Feature dims should be
+padded to the 128-lane width for peak MXU utilization; correctness does not
+depend on it.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 # Shared with kernels/lookback_scan.py — the one-hot lowering and tile
 # padding helpers live in _tiling.py; re-exported here for compatibility.
 from ._tiling import build_round_matrices  # noqa: F401
+from ._tiling import block_scan, resolve_interpret
 
 Op = Callable[[Any, Any], Any]
 
@@ -47,7 +48,14 @@ def _full_spec(*shape):
     return pl.BlockSpec(shape, lambda: (0,) * len(shape))
 
 
-def fused_round(op: Op, y: jax.Array, mats, *, interpret: bool = True) -> jax.Array:
+def _tile_spec(*shape):
+    """Block (1, *shape) of a (T, *shape) array: one tile per grid step."""
+    return pl.BlockSpec((1,) + shape, lambda i: (i,) + (0,) * len(shape))
+
+
+def fused_round(
+    op: Op, y: jax.Array, mats, *, interpret: Optional[bool] = None
+) -> jax.Array:
     """Execute one plan round as a fused gather–combine–scatter kernel.
 
     ``y``: (n, d) wire values; ``mats``: output of :func:`build_round_matrices`
@@ -114,7 +122,7 @@ def fused_round(op: Op, y: jax.Array, mats, *, interpret: bool = True) -> jax.Ar
         in_specs=specs,
         out_specs=_full_spec(n, d),
         out_shape=jax.ShapeDtypeStruct((n, d), y.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -124,13 +132,16 @@ def fused_round(op: Op, y: jax.Array, mats, *, interpret: bool = True) -> jax.Ar
 
 
 def tile_local_scan(
-    op: Op, x: jax.Array, num_tiles: int, *, interpret: bool = True
+    op: Op, x: jax.Array, num_tiles: int, *, interpret: Optional[bool] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-tile inclusive scans and tile totals in one kernel launch.
 
-    ``x``: (n, d) with n divisible by ``num_tiles``.
-    Returns (local, partials): (T, K, d) per-tile inclusive scans and (T, d)
-    tile totals for the global phase.
+    ``x``: (n, d) with n divisible by ``num_tiles``; each grid step holds
+    one whole tile, so size tiles with ``_tiling.vmem_tiles``.
+    Returns (local, partials): (T, K, d) per-tile inclusive scans and
+    (T, 1, d) tile totals for the global phase (a rank-1 ``(d,)`` block
+    would break the TPU's (8, 128) block rule; ``(1, d)`` equals the
+    array's trailing dims and passes).
     """
     n, d = x.shape
     t = num_tiles
@@ -140,55 +151,52 @@ def tile_local_scan(
     x3 = x.reshape(t, k, d)
 
     def kernel(x_ref, y_ref, p_ref):
-        seg = x_ref[0]                                   # (K, d)
-        loc = jax.lax.associative_scan(op, seg, axis=0)
+        loc = block_scan(op, x_ref[0])                   # (K, d)
         y_ref[0] = loc
-        p_ref[0] = loc[k - 1]
+        p_ref[0] = loc[k - 1:]
 
-    block = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda i: (i,) + (0,) * len(shape)
-    )
     local, partials = pl.pallas_call(
         kernel,
         grid=(t,),
-        in_specs=[block(k, d)],
-        out_specs=(block(k, d), block(d)),
+        in_specs=[_tile_spec(k, d)],
+        out_specs=(_tile_spec(k, d), _tile_spec(1, d)),
         out_shape=(
             jax.ShapeDtypeStruct((t, k, d), x.dtype),
-            jax.ShapeDtypeStruct((t, d), x.dtype),
+            jax.ShapeDtypeStruct((t, 1, d), x.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x3)
     return local, partials
 
 
 def tile_apply(
-    op: Op, local: jax.Array, seeds: jax.Array, *, interpret: bool = True
+    op: Op, local: jax.Array, seeds: jax.Array, *,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fold each tile's exclusive global prefix into its local scan.
 
-    ``local``: (T, K, d); ``seeds``: (T, d) where seeds[i] is the inclusive
-    global scan of tiles < i (seeds[0] is ignored — tile 0 passes through).
-    Returns the flat (T*K, d) inclusive scan.
+    ``local``: (T, K, d); ``seeds``: (T, 1, d) where seeds[i] is the
+    inclusive global scan of tiles < i (seeds[0] is ignored — tile 0
+    passes through).  Returns the flat (T*K, d) inclusive scan.
     """
     t, k, d = local.shape
+    # Mosaic refuses to broadcast a (1, d) seed block across the rows
+    # ("Invalid input layout") when the operator then slices lanes; a
+    # whole (8, d) sublane tile, sliced after the load, lowers.
+    seeds8 = jnp.broadcast_to(seeds, (t, 8, d))
 
     def kernel(y_ref, s_ref, o_ref):
         i = pl.program_id(0)
         y = y_ref[0]                                     # (K, d)
-        s = s_ref[0]                                     # (d,)
-        comb = op(jnp.broadcast_to(s[None], y.shape), y)
+        comb = op(jnp.broadcast_to(s_ref[0][:1], y.shape), y)
         o_ref[0] = jnp.where(i == 0, y, comb)
 
-    block = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda i: (i,) + (0,) * len(shape)
-    )
     out = pl.pallas_call(
         kernel,
         grid=(t,),
-        in_specs=[block(k, d), block(d)],
-        out_specs=block(k, d),
+        in_specs=[_tile_spec(k, d), _tile_spec(8, d)],
+        out_specs=_tile_spec(k, d),
         out_shape=jax.ShapeDtypeStruct((t, k, d), local.dtype),
-        interpret=interpret,
-    )(local, seeds)
+        interpret=resolve_interpret(interpret),
+    )(local, seeds8)
     return out.reshape(t * k, d)

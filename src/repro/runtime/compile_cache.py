@@ -11,10 +11,11 @@ remove it:
    (chunk length, frame shape, registration config) signature and reused
    across feeds, sessions and series; hit/miss/compile-second counters are
    surfaced per session (``SeriesResult.report()``).
-2. **JAX persistent cache** (:func:`set_cache_dir`): best-effort opt-in to
-   ``jax_compilation_cache_dir`` so XLA executables survive process restarts
-   (modeled on ``jax.experimental.compilation_cache``).  Unsupported
-   configurations degrade silently — the in-process layer still works.
+2. **JAX persistent cache** (:func:`enable_persistent_cache`): XLA
+   executables survive process restarts.  ``JAX_COMPILATION_CACHE_DIR``,
+   when set, is the cache's one directory; otherwise it is a fixed
+   ``.jax_cache/`` at the checkout root (the path is part of the cache
+   key, so it must not move between runs).  Scripts call it at start.
 3. **Plan store** (:class:`PlanStore`): lowered
    :class:`~repro.core.engine.plan.ExecutionPlan` schedules pickled next to
    the XLA cache.  ``get_plan`` consults the store on an LRU miss, so a
@@ -39,8 +40,11 @@ from typing import Any, Callable, Dict, Optional
 import jax
 
 __all__ = [
+    "CACHE_DIR_ENV",
     "CompileCache",
+    "DEFAULT_CACHE_DIR",
     "PlanStore",
+    "enable_persistent_cache",
     "get_compile_cache",
     "get_plan_store",
     "reset_compile_cache",
@@ -198,31 +202,45 @@ def reset_compile_cache() -> None:
         _plan_store = None
 
 
-def set_cache_dir(path: str) -> bool:
-    """Point both persistence layers at ``path``; create it if needed.
+#: Environment variable that, when set, names the persistent cache's only
+#: directory (JAX reads the same variable).
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    Returns True when JAX's own persistent compilation cache accepted the
-    directory.  False means only the plan store is persistent — older
-    jaxlibs or restricted builds lack the config flag, and the warm start
-    then covers plans and the in-process executable cache only.
+#: The persistent cache's directory when ``CACHE_DIR_ENV`` is unset.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
+
+
+def _point_jax_cache(path: str) -> None:
+    jax.config.update("jax_compilation_cache_dir", path)
+    # Default thresholds skip sub-second compiles — exactly the small
+    # registration kernels this cache exists for.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` if set, else :data:`DEFAULT_CACHE_DIR`.
+    Call before the first compile: JAX decides once per process.
     """
+    path = os.environ.get(CACHE_DIR_ENV) or DEFAULT_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    _point_jax_cache(path)
+    return path
+
+
+def set_cache_dir(path: str) -> None:
+    """Persist lowered plans under ``path``, and XLA executables there too
+    unless ``$JAX_COMPILATION_CACHE_DIR`` already places them."""
     global _plan_store
     path = os.fspath(path)
     os.makedirs(path, exist_ok=True)
     with _state_lock:
         _plan_store = PlanStore(path)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Default thresholds skip sub-second compiles — exactly the small
-        # registration kernels this cache exists for.
-        for flag, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(flag, val)
-            except Exception:  # noqa: BLE001 — flag absent on old jax
-                pass
-        return True
-    except Exception:  # noqa: BLE001 — persistent cache is best-effort
-        return False
+    if not os.environ.get(CACHE_DIR_ENV):
+        _point_jax_cache(path)

@@ -68,6 +68,7 @@ from repro.core.deformation import (
 )
 from repro.core.engine import (
     SHARDED_MIN_DEVICES,
+    Dispatch,
     dispatch as cost_dispatch,
     get_telemetry,
     op_batchable_from,
@@ -148,6 +149,8 @@ class SeriesResult:
     backend: str                         # backend that executed the scan
     op_telemetry: Dict[str, float]       # adapter cost statistics
     scan_stats: Optional[Any] = None     # HierStats when hierarchical ran
+    dispatch: Optional[Dispatch] = None  # cost-model decision of the last
+                                         # scan (None when cfg.backend pins)
     compile_cache: Optional[Dict[str, float]] = None  # session hit/miss/secs
 
     @property
@@ -158,6 +161,8 @@ class SeriesResult:
         lines = [
             f"registered {self.n_frames} frames via backend={self.backend!r}"
         ]
+        if self.dispatch is not None:
+            lines.append(f"  dispatch: {self.dispatch.reason}")
         total = sum(self.timings.values())
         for stage, secs in self.timings.items():
             lines.append(f"  {stage:<12} {secs:8.3f}s")
@@ -322,8 +327,9 @@ class SeriesSession:
         self.cfg = cfg if cfg is not None else RegisterSeriesConfig()
         self.id = session_id or f"series{next(_session_ids)}"
         if compile_cache_dir is not None:
-            # Best-effort: enables jax's persistent XLA cache + the plan
-            # store; the in-process executable cache works regardless.
+            # The plan store, and XLA's persistent cache unless
+            # $JAX_COMPILATION_CACHE_DIR places it; the in-process
+            # executable cache works regardless.
             set_cache_dir(compile_cache_dir)
         self.pool = pool if pool is not None else get_default_pool()
         self.telemetry = get_telemetry(
@@ -342,6 +348,7 @@ class SeriesSession:
             "hits": 0, "misses": 0, "compile_s": 0.0,
         }
         self._backend_used: Optional[str] = None
+        self._dispatch = None
         self._scan_stats = None
         # Pin the device mesh once: every suffix scan of this series runs
         # on the same devices, so sharded executables (and their boundary
@@ -558,6 +565,7 @@ class SeriesSession:
                 )
                 # Execute exactly what the dispatcher decided (its circuit,
                 # segment and thread counts — unless the config pins them).
+                self._dispatch = d
                 backend_used = d.backend
                 if algorithm is None:
                     algorithm = d.algorithm
@@ -627,6 +635,7 @@ class SeriesSession:
             backend=self._backend_used or "none",
             op_telemetry=self.telemetry.summary(),
             scan_stats=self._scan_stats,
+            dispatch=self._dispatch,
             compile_cache=dict(self._compile),
         )
 
@@ -793,8 +802,9 @@ def open_series(
     ``pool``: the :class:`~repro.runtime.scheduler.WorkerPool` to execute
     on (process-wide shared pool by default).  ``checkpoint_dir`` enables
     ``session.checkpoint()`` / :meth:`SeriesSession.restore`.
-    ``compile_cache_dir`` points the persistent compilation cache (XLA
-    executables + lowered plans) at a directory so restarts warm-start
+    ``compile_cache_dir`` points the persistent compilation cache (lowered
+    plans, and XLA executables unless ``$JAX_COMPILATION_CACHE_DIR`` is
+    set) at a directory so restarts warm-start
     (:mod:`repro.runtime.compile_cache`).
     """
     return SeriesSession(

@@ -195,3 +195,33 @@ def test_series_session_warm_start(tmp_path, clean_cache_state):
         atol=1e-6,
     )
     assert "compile cache:" in warm.report()
+
+
+# ------------------------------------------------ persistent cache placement
+
+
+def test_persistent_cache_placement(subproc, tmp_path):
+    """``$JAX_COMPILATION_CACHE_DIR`` is the cache's one directory: compiled
+    programs land there, and a session's ``compile_cache_dir`` moves only
+    the plan store.  Unset, the cache is ``.jax_cache/`` at the checkout
+    root.  A child process: JAX fixes its cache once per process."""
+    import os
+
+    env_dir, plans = tmp_path / "env", tmp_path / "plans"
+    out = subproc(f"""
+import os
+os.environ["JAX_COMPILATION_CACHE_DIR"] = {str(env_dir)!r}
+import jax, jax.numpy as jnp
+from repro.runtime import compile_cache as cc
+print(cc.enable_persistent_cache())
+cc.set_cache_dir({str(plans)!r})
+jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(8)).block_until_ready()
+del os.environ["JAX_COMPILATION_CACHE_DIR"]
+print(cc.DEFAULT_CACHE_DIR)
+""", devices=1)
+    placed, default = out.split()
+    assert placed == str(env_dir)
+    assert any(p.name.startswith("jit_") for p in env_dir.iterdir())
+    assert not any(p.name.startswith("jit_") for p in plans.iterdir())
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert default == os.path.join(repo, ".jax_cache")

@@ -6,7 +6,7 @@ import pytest
 DISTRIBUTED_SNIPPET = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core.distributed import (
     collective_scan, hierarchical_collective_scan, distributed_blocked_scan)
@@ -71,7 +71,7 @@ HIER2_SNIPPET = r"""
 import math
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core import distributed as dist
 from repro.core.distributed import (

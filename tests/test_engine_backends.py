@@ -130,7 +130,7 @@ def test_element_noncommutative(backend):
 COLLECTIVE_SNIPPET = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core.engine import scan
 

@@ -62,17 +62,27 @@ def test_exscan_plan_round0_moves():
     assert all(out < 8 and src >= 8 for src, out, _f in e_moves)
 
 
-def test_axis_size_guard(monkeypatch):
-    """_axis_size: explicit size wins; a jax without jax.lax.axis_size gets
-    a clear error naming the axis_size= argument instead of AttributeError."""
+def test_axis_size_guard():
+    """_axis_size: an explicit size wins; otherwise the mesh axis's static
+    size, read with jax.lax.axis_size inside shard_map."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core.distributed import _axis_size
 
     assert _axis_size("x", 8) == 8
-    monkeypatch.delattr(jax.lax, "axis_size", raising=False)
-    with pytest.raises(ValueError, match="axis_size="):
-        _axis_size("x", None)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("x",))
+    seen = []
+
+    def body(v):
+        seen.append(_axis_size("x", None))
+        return v
+
+    jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"))(
+        jnp.zeros(4)
+    )
+    assert seen == [1]
 
 
 # ---------------------------------------------------------------------------

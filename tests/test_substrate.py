@@ -74,7 +74,7 @@ def test_int8_roundtrip():
 def test_compressed_psum_error_feedback(subproc):
     out = subproc(r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from functools import partial
 from repro.optim.compress import compressed_psum
