@@ -37,6 +37,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.runtime.scheduler import get_default_pool
+from repro.runtime.spans import span
 
 from .backends import exec_element, exec_vector, register_backend
 from .plan import ExecutionPlan, get_plan
@@ -284,20 +285,23 @@ def _exec_hier_element(
             return pscan, st.boundaries, st, st.total_ops + len(pscan) - 1
 
     t0 = time.perf_counter()
-    if cross:
-        seg_results = pool.run_tasks(
-            [functools.partial(reduce_segment_cross, i) for i in range(s)],
-            label="hier_reduce_cross",
-        )
-        # Boundaries moved with the steals: report the segments' final spans.
-        bounds = [(r[1][0][0], r[1][-1][1]) for r in seg_results]
-    elif s == 1:
-        seg_results = [reduce_segment(*bounds[0])]
-    else:
-        seg_results = pool.run_tasks(
-            [functools.partial(reduce_segment, lo, hi) for lo, hi in bounds],
-            label="hier_reduce",
-        )
+    with span("scan.phase1"):
+        if cross:
+            seg_results = pool.run_tasks(
+                [functools.partial(reduce_segment_cross, i) for i in range(s)],
+                label="hier_reduce_cross",
+            )
+            # Boundaries moved with the steals: report the segments' final
+            # spans.
+            bounds = [(r[1][0][0], r[1][-1][1]) for r in seg_results]
+        elif s == 1:
+            seg_results = [reduce_segment(*bounds[0])]
+        else:
+            seg_results = pool.run_tasks(
+                [functools.partial(reduce_segment, lo, hi)
+                 for lo, hi in bounds],
+                label="hier_reduce",
+            )
     phase["reduce"] = time.perf_counter() - t0
     for _pscan, _intervals, _st, seg_ops in seg_results:
         ops_count += seg_ops
@@ -308,7 +312,8 @@ def _exec_hier_element(
     if s > 1:
         if plan is None or plan.n != s or plan.exclusive:
             plan = get_plan("ladner_fischer", s)
-        scanned, _ = exec_element(op, plan, totals)
+        with span("scan.phase2"):
+            scanned, _ = exec_element(op, plan, totals)
         ops_count += plan.work()
     else:
         scanned = totals
@@ -319,21 +324,6 @@ def _exec_hier_element(
     t0 = time.perf_counter()
     out: List[Any] = [None] * n
     jobs: List[Tuple[int, int, Any]] = []
-    for i, (pscan, intervals, _st, _ops) in enumerate(seg_results):
-        if i == 0:
-            base = seed
-        elif seed is None:
-            base = scanned[i - 1]
-        else:
-            base = op(seed, scanned[i - 1])
-            ops_count += 1  # seed combines execute the operator: count them
-        for j, (lo, hi) in enumerate(intervals):
-            if j == 0:
-                sj = base
-            else:
-                sj = pscan[j - 1] if base is None else op(base, pscan[j - 1])
-                ops_count += 0 if base is None else 1
-            jobs.append((lo, hi, sj))
 
     def apply_interval(job):
         lo, hi, acc = job
@@ -344,15 +334,32 @@ def _exec_hier_element(
             k += 1
         return k - (1 if job[2] is None else 0)
 
-    if len(jobs) == 1:
-        ops_count += apply_interval(jobs[0])
-    else:
-        ops_count += sum(
-            pool.run_tasks(
-                [functools.partial(apply_interval, j) for j in jobs],
-                label="hier_apply",
+    with span("scan.phase3"):
+        for i, (pscan, intervals, _st, _ops) in enumerate(seg_results):
+            if i == 0:
+                base = seed
+            elif seed is None:
+                base = scanned[i - 1]
+            else:
+                base = op(seed, scanned[i - 1])
+                ops_count += 1  # seed combines execute the operator
+            for j, (lo, hi) in enumerate(intervals):
+                if j == 0:
+                    sj = base
+                else:
+                    sj = (pscan[j - 1] if base is None
+                          else op(base, pscan[j - 1]))
+                    ops_count += 0 if base is None else 1
+                jobs.append((lo, hi, sj))
+        if len(jobs) == 1:
+            ops_count += apply_interval(jobs[0])
+        else:
+            ops_count += sum(
+                pool.run_tasks(
+                    [functools.partial(apply_interval, j) for j in jobs],
+                    label="hier_apply",
+                )
             )
-        )
     phase["apply"] = time.perf_counter() - t0
 
     last_stats = HierStats(

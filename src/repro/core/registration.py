@@ -24,6 +24,9 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.runtime.spans import span
 
 from .deformation import (
     Deformation,
@@ -73,6 +76,8 @@ class RegResult(NamedTuple):
     deformation: Deformation
     distance: jax.Array          # final 1 - NCC
     iterations: jax.Array        # total gradient iterations (cost proxy)
+    # Iterations per pyramid level, coarse to fine: shape (..., levels).
+    level_iterations: Optional[jax.Array] = None
 
 
 def _minimize_level(
@@ -154,14 +159,36 @@ def _register_pair(ref, tmpl, init, cfg) -> RegResult:
     tmps = _pyramid(tmpl, cfg.levels)
     scale = 2.0 ** (cfg.levels - 1)
     d = {"angle": init["angle"], "shift": init["shift"] / scale}
-    total_iters = jnp.zeros((), jnp.int32)
     dist = jnp.zeros(())
+    level_iters = []
     for lvl, (r, t) in enumerate(zip(refs, tmps)):
         d, dist, iters = _minimize_level(r, t, d, cfg)
-        total_iters = total_iters + iters
+        level_iters.append(iters)
         if lvl != len(refs) - 1:
             d = {"angle": d["angle"], "shift": d["shift"] * 2.0}
-    return RegResult(d, dist, total_iters)
+    level_iters = jnp.stack(level_iters)
+    return RegResult(d, dist, jnp.sum(level_iters), level_iters)
+
+
+def lane_work(level_iterations, shape: Tuple[int, int]) -> Tuple[int, int]:
+    """Pixel-steps of a vmapped batch of pairs: ``(useful, issued)``.
+
+    ``level_iterations`` is the batch's ``(lanes, levels)`` host array.  A
+    vmapped ``while_loop`` runs its body on every lane until the slowest
+    lane stops, and a step on level ``l`` costs in proportion to its pixels
+    ``p_l``: useful = sum_l p_l * sum_lanes it, issued = sum_l p_l * lanes *
+    max_lanes it.
+    """
+    its = np.asarray(level_iterations, np.int64)
+    h, w = shape
+    pixels = []
+    for _ in range(its.shape[-1]):
+        pixels.append(h * w)
+        h, w = h // 2, w // 2
+    pixels = np.asarray(pixels[::-1], np.int64)    # coarse -> fine
+    lanes = its.shape[0]
+    return (int(its.sum(axis=0) @ pixels),
+            int(lanes * its.max(axis=0) @ pixels))
 
 
 # ---------------------------------------------------------------------------
@@ -290,6 +317,10 @@ class RegistrationOperator:
       :class:`~repro.core.engine.telemetry.OpTelemetry`; the adapter exposes
       ``op_cost_estimate`` so the dispatcher routes the *next* call from
       observed costs (data-dependent iteration counts drift over a series).
+      That time is host time: an application returns before its device
+      work ends, but waits for room where the device's queue is full.
+      Each one is also a ``repro.fn_b`` span in the profiler's trace,
+      tagged with ``session``.
     * **guess check** — when ``skip_tol`` is set, the composed initial
       guess phi_{j,k} o phi_{i,j} is scored first and refinement is skipped
       when it already registers within tolerance.  ``fused=True`` scores it
@@ -327,10 +358,12 @@ class RegistrationOperator:
         skip_tol: Optional[float] = None,
         fused: Optional[bool] = None,
         tile: int = 32,
+        session: Optional[str] = None,
     ):
         from .engine.telemetry import OpTelemetry
 
         self.registrar = registrar
+        self.session = session
         # A fresh channel per adapter by default, so per-run statistics stay
         # per-run; pass get_telemetry(name) explicitly to accumulate across
         # runs under one process-wide channel.
@@ -347,8 +380,6 @@ class RegistrationOperator:
                 "(only 2-D gathers are supported). Leave fused unset."
             )
         self.fused = bool(fused) and fused_ncc_eligible((h, w), tile)
-        self.skipped = 0
-        self.refined = 0
         self._count_lock = threading.Lock()
         self._elem_prior: Optional[list] = None
         self._elem_obs: dict = {}
@@ -423,54 +454,51 @@ class RegistrationOperator:
     def __call__(self, a: RegElement, b: RegElement) -> RegElement:
         import time
 
-        t0 = time.perf_counter()
-        reg = self.registrar
-        sig = (
-            tuple(reg.frames.shape[1:]), reg.cfg, reg.refine,
-            self.skip_tol is not None, self.fused,
-        )
-        # Cold until the first call under this signature *completes*:
-        # concurrent calls that start while the compile is in flight all
-        # block on it and would otherwise poison the EMA with its wall time.
-        with RegistrationOperator._warm_lock:
-            cold = sig not in RegistrationOperator._warm_signatures
-        # Attribute the cost to whichever operands ARE single scan
-        # elements — left folds (stealing_reduce extending left) pass the
-        # fresh element as ``a`` and the partial as ``b``, right folds the
-        # reverse; indexing ``b`` unconditionally would credit half of
-        # phase 1 to one unrelated right-edge element.  When both are
-        # single (a thread's first combine) the registration involves both
-        # frames, so both EMAs receive the sample.  Partial∘partial
-        # combines (pscan, phase 2) have no single element and are skipped.
-        elem_idxs = [e.k - 1 for e in (a, b) if e.k - e.i == 1]
-        try:
-            assert a.k == b.i, f"non-adjacent elements {a.i, a.k} . {b.i, b.k}"
-            guess = compose(a.deformation, b.deformation)
-            if not reg.refine:
-                return RegElement(guess, a.i, b.k)
-            if self.skip_tol is not None:
-                dist = self._guess_distance(
-                    reg.frames[a.i], reg.frames[b.k], guess
-                )
-                if float(dist) < self.skip_tol:
-                    with self._count_lock:
-                        self.skipped += 1
-                    return RegElement(guess, a.i, b.k)
-            res = register_pair(reg.frames[a.i], reg.frames[b.k], guess, reg.cfg)
-            with self._count_lock:
-                self.refined += 1
-            return RegElement(res.deformation, a.i, b.k)
-        finally:
-            dt = time.perf_counter() - t0
-            self.telemetry.record(dt, compile=cold)
+        with span("fn_b", self.session, i=a.i, k=b.k):
+            t0 = time.perf_counter()
+            reg = self.registrar
+            sig = (
+                tuple(reg.frames.shape[1:]), reg.cfg, reg.refine,
+                self.skip_tol is not None, self.fused,
+            )
+            # Cold until the first call under this signature *completes*:
+            # concurrent calls that start while the compile is in flight all
+            # block on it and would otherwise poison the EMA with its wall time.
             with RegistrationOperator._warm_lock:
-                RegistrationOperator._warm_signatures.add(sig)
-            # A compile-dominated sample is no basis for per-element cost
-            # ranking either — skip the observation, keep the prior.
-            if elem_idxs and not cold:
-                with self._count_lock:
-                    for j in elem_idxs:
-                        prev = self._elem_obs.get(j)
-                        self._elem_obs[j] = (
-                            dt if prev is None else 0.5 * prev + 0.5 * dt
-                        )
+                cold = sig not in RegistrationOperator._warm_signatures
+            # Attribute the cost to whichever operands ARE single scan
+            # elements — left folds (stealing_reduce extending left) pass the
+            # fresh element as ``a`` and the partial as ``b``, right folds the
+            # reverse; indexing ``b`` unconditionally would credit half of
+            # phase 1 to one unrelated right-edge element.  When both are
+            # single (a thread's first combine) the registration involves both
+            # frames, so both EMAs receive the sample.  Partial∘partial
+            # combines (pscan, phase 2) have no single element and are skipped.
+            elem_idxs = [e.k - 1 for e in (a, b) if e.k - e.i == 1]
+            try:
+                assert a.k == b.i, f"non-adjacent elements {a.i, a.k} . {b.i, b.k}"
+                guess = compose(a.deformation, b.deformation)
+                if not reg.refine:
+                    return RegElement(guess, a.i, b.k)
+                if self.skip_tol is not None:
+                    dist = self._guess_distance(
+                        reg.frames[a.i], reg.frames[b.k], guess
+                    )
+                    if float(dist) < self.skip_tol:
+                        return RegElement(guess, a.i, b.k)
+                res = register_pair(reg.frames[a.i], reg.frames[b.k], guess, reg.cfg)
+                return RegElement(res.deformation, a.i, b.k)
+            finally:
+                dt = time.perf_counter() - t0
+                self.telemetry.record(dt, compile=cold)
+                with RegistrationOperator._warm_lock:
+                    RegistrationOperator._warm_signatures.add(sig)
+                # A compile-dominated sample is no basis for per-element cost
+                # ranking either — skip the observation, keep the prior.
+                if elem_idxs and not cold:
+                    with self._count_lock:
+                        for j in elem_idxs:
+                            prev = self._elem_obs.get(j)
+                            self._elem_obs[j] = (
+                                dt if prev is None else 0.5 * prev + 0.5 * dt
+                            )

@@ -55,6 +55,7 @@ from repro.analysis.invariants import (
 )
 from repro.analysis.sync import invariants_enabled, sync_point
 from repro.runtime.scheduler import get_default_pool
+from repro.runtime.spans import span
 
 from .engine.backends import exec_element
 from .engine.plan import ExecutionPlan, get_plan
@@ -462,7 +463,8 @@ def work_stealing_scan(
     events_lock = threading.Lock() if checking else None
     reduce_fn = stealing_reduce if stealing else static_reduce
     sync_point("phase1.reduce")
-    partials, stats = reduce_fn(op, items, num_threads, pool=pool)
+    with span("scan.phase1"):
+        partials, stats = reduce_fn(op, items, num_threads, pool=pool)
     if checking:
         record_events(events, "p1_done", 0)
 
@@ -470,7 +472,8 @@ def work_stealing_scan(
     if plan is None or plan.n != len(partials):
         plan = get_plan(algorithm, len(partials))
     sync_point("phase2.scan")
-    scanned, _ = exec_element(op, plan, partials)
+    with span("scan.phase2"):
+        scanned, _ = exec_element(op, plan, partials)
     if checking:
         record_events(events, "p2_done", -1)
     stats.total_ops += plan.work()
@@ -479,16 +482,6 @@ def work_stealing_scan(
     out: List[Any] = [None] * n
     bounds = stats.boundaries
     seeds: List[Any] = []
-    for i in range(len(bounds)):
-        if i == 0:
-            seeds.append(seed)
-        elif seed is None:
-            seeds.append(scanned[i - 1])
-        else:
-            # Seed combines execute the operator — they count toward the
-            # total-work claim (~3N for a seeded full scan) like any other.
-            seeds.append(op(seed, scanned[i - 1]))
-            stats.total_ops += 1
 
     def apply_worker(tid: int) -> None:
         sync_point("phase3.apply")
@@ -501,10 +494,21 @@ def work_stealing_scan(
             acc = items[j] if acc is None else op(acc, items[j])
             out[j] = acc
 
-    pool.run_tasks(
-        [functools.partial(apply_worker, i) for i in range(len(bounds))],
-        label="seeded_apply",
-    )
+    with span("scan.phase3"):
+        for i in range(len(bounds)):
+            if i == 0:
+                seeds.append(seed)
+            elif seed is None:
+                seeds.append(scanned[i - 1])
+            else:
+                # Seed combines execute the operator — they count toward the
+                # total-work claim (~3N for a seeded full scan) like any other.
+                seeds.append(op(seed, scanned[i - 1]))
+                stats.total_ops += 1
+        pool.run_tasks(
+            [functools.partial(apply_worker, i) for i in range(len(bounds))],
+            label="seeded_apply",
+        )
     if checking:
         # Phase-3 applies must observe both completions: the event log is
         # append-ordered, so any apply recorded before p1_done/p2_done

@@ -50,11 +50,12 @@ snapshot and continues feeding.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +82,10 @@ from repro.core.registration import (
     RegistrationConfig,
     RegistrationOperator,
     SeriesRegistrar,
+    lane_work,
     register_pair,
 )
+from repro.runtime import spans
 from repro.runtime.compile_cache import get_compile_cache, set_cache_dir
 from repro.runtime.scheduler import get_default_pool
 
@@ -125,18 +128,27 @@ class SeriesResult:
     ``timings`` maps pipeline stage -> cumulative wall-clock **seconds**
     spent in that stage over the session's whole life (a ``result()``
     mid-stream reports the seconds so far, and later results include the
-    earlier work):
+    earlier work).  Each stage but ``compile`` is also a host span of the
+    profiler's trace, named in brackets:
 
-    * ``ingest``     — slicing/stacking fed chunks into frame pairs;
+    * ``ingest``     — a fed chunk made a device array and waited for
+      (``repro.feed.ingest``);
     * ``compile``    — XLA trace/compile time for the vmapped function-A
       cohorts (kept out of ``preprocess`` so cost telemetry and speedup
       numbers are not poisoned by one-off compilation);
     * ``preprocess`` — function A proper: batched pairwise registration of
-      new frame pairs (paper §3's element construction);
+      new frame pairs (paper §3's element construction), up to the return
+      of their iteration counts (``repro.fn_a``);
     * ``scan``       — the (.)_B prefix scan over elements (work-stealing /
-      hierarchical / sequential, whichever the dispatcher chose);
+      hierarchical / sequential, whichever the dispatcher chose) as host
+      time (``repro.scan``).  It does not wait for function B's device
+      work at its end: the device time of the applications still queued
+      then lands in the next stage that blocks, the next feed's
+      ``preprocess`` or ``result()``'s ``compose``.  Nor is it issue time
+      alone: an application issued while the device's queue is full
+      waits for room;
     * ``compose``    — batching per-element deformations into the stacked
-      ``Deformation`` output.
+      ``Deformation`` output (``repro.result``).
 
     A plain dataclass of already-materialised values: safe to read from
     any thread once returned, and never mutated by the session afterwards
@@ -408,14 +420,13 @@ class SeriesSession:
         the session's feed lock.
         """
         self._check_open()
-        with self._feed_lock:
-            t0 = time.perf_counter()
-            chunk = jnp.asarray(chunk)
-            jax.block_until_ready(chunk)
-            self._timings["ingest"] += time.perf_counter() - t0
+        with spans.serving(self.id), spans.span("feed", frames=len(chunk)), \
+                self._feed_lock:
+            with self._stage("ingest", "feed.ingest"):
+                chunk = jnp.asarray(chunk)
+                jax.block_until_ready(chunk)
             if chunk.shape[0] == 0:
                 return self
-            t0 = time.perf_counter()
             prev_last = self._store.last()
             refs = (
                 chunk[:-1] if prev_last is None
@@ -423,76 +434,100 @@ class SeriesSession:
             )
             tmps = chunk if prev_last is not None else chunk[1:]
             new_elems: List[RegElement] = []
-            compile_before = self._compile["compile_s"]
-            if refs.shape[0]:
-                reg_cfg = self.cfg.registration
-                # AOT-compiled per (pair fn, batch, frame shape, dtype,
-                # config) signature: one compile per signature per process,
-                # shared across feeds and sessions.  The live module-level
-                # ``register_pair`` is part of the key so a swapped
-                # implementation never reuses a stale executable.
-                pair_fn = get_compile_cache().get_compiled(
-                    ("pair_vmap", register_pair, int(refs.shape[0]),
-                     tuple(refs.shape[1:]), str(refs.dtype), reg_cfg),
-                    lambda: jax.vmap(
-                        lambda r, t: register_pair(r, t, None, reg_cfg)
-                    ),
-                    lower_args=(refs, tmps),
-                    counters=self._compile,
-                )
-                res = pair_fn(refs, tmps)
-                jax.block_until_ready(res.deformation)
-                first = self._store.n - 1 if self._store.n else 0
-                new_elems = [
-                    RegElement(
-                        jax.tree.map(lambda a, i=i: a[i], res.deformation),
-                        first + i, first + i + 1,
-                    )
-                    for i in range(int(refs.shape[0]))
-                ]
-                self._pair_iters.extend(
-                    int(v) for v in jax.device_get(res.iterations)
-                )
+            lanes = int(refs.shape[0])
+            if lanes:
+                pre_before = self._timings["preprocess"]
+                with self._stage("preprocess", "fn_a", lanes=lanes):
+                    new_elems = self._register_pairs(refs, tmps)
+                self._pre_pairs += lanes
+                self._pre_seconds += self._timings["preprocess"] - pre_before
             self._store.append_chunk(chunk)
-            dt = time.perf_counter() - t0
-            # Compile seconds are accounted to their own stage: they used
-            # to inflate "preprocess" AND the telemetry prime derived from
-            # it (sec/pair), so the dispatcher planned the first suffix
-            # scan around a compile-dominated operator cost.
-            dt_compile = self._compile["compile_s"] - compile_before
-            dt -= dt_compile
-            self._timings["compile"] += dt_compile
-            self._timings["preprocess"] += dt
             if new_elems:
-                self._pre_pairs += len(new_elems)
-                self._pre_seconds += dt
                 self._scan_suffix(new_elems)
             # O(1) residency: only frame 0 and the boundary frame can be
             # touched by future feeds.
             self._store.evict({0, self._store.n - 1})
         return self
 
+    def _register_pairs(self, refs, tmps) -> List[RegElement]:
+        """Function A on the batch of pairs ``(refs[i], tmps[i])``: the new
+        scan elements.  Records the pairs' iteration counts and, from the
+        same fetch, the ``repro.fn_a.lanes`` counter."""
+        reg_cfg = self.cfg.registration
+        # AOT-compiled per (pair fn, batch, frame shape, dtype, config)
+        # signature: one compile per signature per process, shared across
+        # feeds and sessions.  The live module-level ``register_pair`` is
+        # part of the key so a swapped implementation never reuses a stale
+        # executable.
+        pair_fn = get_compile_cache().get_compiled(
+            ("pair_vmap", register_pair, int(refs.shape[0]),
+             tuple(refs.shape[1:]), str(refs.dtype), reg_cfg),
+            lambda: jax.vmap(lambda r, t: register_pair(r, t, None, reg_cfg)),
+            lower_args=(refs, tmps),
+            counters=self._compile,
+        )
+        res = pair_fn(refs, tmps)
+        jax.block_until_ready(res.deformation)
+        first = self._store.n - 1 if self._store.n else 0
+        new_elems = [
+            RegElement(
+                jax.tree.map(lambda a, i=i: a[i], res.deformation),
+                first + i, first + i + 1,
+            )
+            for i in range(int(refs.shape[0]))
+        ]
+        iters, level_iters = jax.device_get(
+            (res.iterations, res.level_iterations)
+        )
+        self._pair_iters.extend(int(v) for v in iters)
+        if level_iters is not None:
+            useful, issued = lane_work(level_iters, tuple(refs.shape[1:]))
+            with spans.span("fn_a.lanes", lanes=len(new_elems),
+                            useful=useful, issued=issued):
+                pass
+        return new_elems
+
+    @contextlib.contextmanager
+    def _stage(self, stage: str, name: str, **stats) -> Iterator[None]:
+        """Time one pipeline stage into ``_timings[stage]`` and span the
+        same interval as ``repro.<name>``, so the two cannot drift.
+
+        Compile seconds the session's executable cache counts meanwhile go
+        to the ``compile`` stage: they used to inflate ``preprocess`` and
+        the telemetry prime derived from it (sec/pair), so the dispatcher
+        planned the first suffix scan around a compile-dominated cost.
+        """
+        compile_before = self._compile["compile_s"]
+        t0 = time.perf_counter()
+        try:
+            with spans.span(name, self.id, **stats):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            dt_compile = self._compile["compile_s"] - compile_before
+            self._timings["compile"] += dt_compile
+            self._timings[stage] += dt - dt_compile
+
     def _scan_suffix(self, new_elems: List[RegElement]) -> None:
         cfg = self.cfg
-        t0 = time.perf_counter()
+        scan_before = self._timings["scan"]
         seed = self._elements[-1] if self._elements else None
         first_elem = len(self._elements)
         # Compile-classified applications still *happened* this feed — the
         # summary counts work, the EMA alone excludes compile time.
         ops_before = self.telemetry.calls + self.telemetry.compile_calls
-        if not cfg.refine:
-            out = self._compose_suffix(new_elems, seed)
-            backend_used = cfg.backend or "vector"
-        else:
-            out, backend_used = self._refine_suffix(new_elems, seed)
-        self._backend_used = backend_used
-        self._elements.extend(out)
-        dt = time.perf_counter() - t0
-        self._timings["scan"] += dt
+        with self._stage("scan", "scan", elements=len(new_elems)):
+            if not cfg.refine:
+                out = self._compose_suffix(new_elems, seed)
+                backend_used = cfg.backend or "vector"
+            else:
+                out, backend_used = self._refine_suffix(new_elems, seed)
+            self._backend_used = backend_used
+            self._elements.extend(out)
         self._summaries.append(_ChunkSummary(
             first_elem=first_elem,
             n_elems=len(new_elems),
-            seconds=dt,
+            seconds=self._timings["scan"] - scan_before,
             ops=self.telemetry.calls + self.telemetry.compile_calls
                 - ops_before,
         ))
@@ -537,6 +572,7 @@ class SeriesSession:
             telemetry=self.telemetry,
             skip_tol=cfg.skip_tol,
             fused=cfg.fused_ncc,
+            session=self.id,
         )
         sec_per_pair = self._pre_seconds / max(self._pre_pairs, 1)
         if op.op_cost_estimate is None and sec_per_pair > 0:
@@ -554,15 +590,16 @@ class SeriesSession:
         cross_steal = cfg.cross_steal
         with self.pool.tenant():
             if backend_used is None:
-                d = cost_dispatch(
-                    n_new, domain="element",
-                    op_cost=op.op_cost_estimate,
-                    workers=pool_aware_workers(self.pool, cfg.workers),
-                    op_imbalance=op.op_imbalance_estimate,
-                    pool_occupancy=self.pool.occupancy(),
-                    op_batchable=op_batchable_from(op),
-                    devices=self._devices,
-                )
+                with spans.span("scan.dispatch"):
+                    d = cost_dispatch(
+                        n_new, domain="element",
+                        op_cost=op.op_cost_estimate,
+                        workers=pool_aware_workers(self.pool, cfg.workers),
+                        op_imbalance=op.op_imbalance_estimate,
+                        pool_occupancy=self.pool.occupancy(),
+                        op_batchable=op_batchable_from(op),
+                        devices=self._devices,
+                    )
                 # Execute exactly what the dispatcher decided (its circuit,
                 # segment and thread counts — unless the config pins them).
                 self._dispatch = d
@@ -618,16 +655,15 @@ class SeriesSession:
             raise ValueError(
                 f"register_series needs >= 2 frames, got {self._store.n}"
             )
-        t0 = time.perf_counter()
-        all_defs = [identity_deformation()] + [
-            e.deformation for e in self._elements
-        ]
-        deformations = jax.tree.map(
-            lambda *ts: jnp.stack([jnp.asarray(t) for t in ts], axis=0),
-            *all_defs,
-        )
-        jax.block_until_ready(deformations)
-        self._timings["compose"] += time.perf_counter() - t0
+        with self._stage("compose", "result"):
+            all_defs = [identity_deformation()] + [
+                e.deformation for e in self._elements
+            ]
+            deformations = jax.tree.map(
+                lambda *ts: jnp.stack([jnp.asarray(t) for t in ts], axis=0),
+                *all_defs,
+            )
+            jax.block_until_ready(deformations)
         return SeriesResult(
             deformations=deformations,
             elements=list(self._elements),
