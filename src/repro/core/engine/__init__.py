@@ -60,6 +60,7 @@ from .cost import (
     dispatch,
     measure_op_cost,
     pool_aware_workers,
+    worker_budget,
 )
 from .plan import ExecutionPlan, PlanRound, get_plan, lower, plan_cache
 from .telemetry import (
@@ -67,6 +68,7 @@ from .telemetry import (
     element_costs_from,
     get_telemetry,
     op_batchable_from,
+    op_concurrency_from,
     op_cost_from,
     op_imbalance_from,
     release_telemetry,
@@ -95,6 +97,7 @@ __all__ = [
     "SHARDED_MIN_DEVICES",
     "SHARDED_MIN_N",
     "pool_aware_workers",
+    "worker_budget",
     "get_default_pool",
     "release_telemetry",
     "scan",
@@ -117,6 +120,7 @@ __all__ = [
     "OpTelemetry",
     "get_telemetry",
     "op_batchable_from",
+    "op_concurrency_from",
     "op_cost_from",
     "op_imbalance_from",
     "element_costs_from",
@@ -221,6 +225,8 @@ def scan(
     count, so concurrent series shrink each other's planned parallelism
     and a saturated pool shifts small series to the work-optimal
     sequential chain instead of queueing (``cost.POOL_BUSY_OCCUPANCY``).
+    An operator that reports ``op_concurrency`` (the devices that hold its
+    data) caps that parallelism too: at 1 the chain runs.
 
     ``devices``/``mesh``: local device count / explicit 1-D jax mesh for
     the multi-device ``sharded`` backend (one long series split into
@@ -372,7 +378,8 @@ def _scan_impl(
                      pool_occupancy=occupancy,
                      op_batchable=op_batchable_from(op),
                      accel=_accel_available(),
-                     devices=devices)
+                     devices=devices,
+                     op_concurrency=op_concurrency_from(op))
         backend = d.backend
         if where is not None and backend in ("blocked", "worksteal",
                                              "hierarchical"):

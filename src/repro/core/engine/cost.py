@@ -11,7 +11,11 @@ algorithm depends on the operator-cost regime —
 * **expensive** operators (the image-registration operator: seconds per
   application): operator applications dominate everything; choose
   reduce-then-scan so total work stays ~2N, and use the work-stealing
-  executor (``worksteal``) so load imbalance does not serialize phase 1.
+  executor (``worksteal``) so load imbalance does not serialize phase 1 —
+  when there are executors to run its phases at once.  The worker budget
+  is capped at the operator's own concurrency (``op_concurrency``: the
+  devices that hold its data); at one, the extra applications of the
+  parallel phases buy nothing, and the work-optimal chain runs instead.
 * in between, per-element execution (``element``) avoids the batching
   overhead that vectorization pays for operators that do not fuse.
 
@@ -162,6 +166,17 @@ def pool_aware_workers(pool, workers: Optional[int]) -> Optional[int]:
     return max(1, _default_workers() // tenants)
 
 
+def worker_budget(workers: Optional[int],
+                  op_concurrency: Optional[int] = None) -> int:
+    """Element-domain worker budget: the hint (else the host's cores),
+    capped at the executors that can run the operator's applications at
+    the same time (None: unknown, no cap)."""
+    w = workers if workers is not None else _default_workers()
+    if op_concurrency is not None:
+        w = min(w, max(op_concurrency, 1))
+    return w
+
+
 def _largest_divisor_at_most(n: int, cap: int) -> int:
     for p in range(min(cap, n), 0, -1):
         if n % p == 0:
@@ -180,6 +195,7 @@ def dispatch(
     op_batchable: Optional[bool] = None,
     accel: bool = False,
     devices: Optional[int] = None,
+    op_concurrency: Optional[int] = None,
 ) -> Dispatch:
     """Pick backend + circuit + block size for one scan call.
 
@@ -205,6 +221,11 @@ def dispatch(
     ``SHARDED_MIN_DEVICES``+ a long batchable series runs across all of
     them (``sharded`` backend: shard_map phase 1 with boundary stealing,
     Träff exscan phase 2).
+    ``op_concurrency``: how many of the operator's applications can run
+    at the same time (``op_concurrency_from``: the devices that hold its
+    data).  Caps the element-domain worker budget; at 1 no parallel phase
+    applies and a seeded scan runs the work-optimal chain.  None (the
+    operator does not say) leaves the budget as it is.
     """
     if n <= 1:
         return Dispatch("element" if domain == "element" else "vector",
@@ -220,6 +241,7 @@ def dispatch(
     )
 
     if domain == "element":
+        w = worker_budget(w, op_concurrency)
         if sharded_ok:
             return Dispatch(
                 "sharded", "exscan", devices=devices,

@@ -192,6 +192,23 @@ def op_batchable_from(op) -> Optional[bool]:
     return bool(est) if est is not None else None
 
 
+def op_concurrency_from(op) -> Optional[int]:
+    """How many of the operator's applications can run at the same time.
+
+    Adapters expose ``op_concurrency`` (int or zero-arg callable) from
+    where their data lives — e.g. the accelerators that hold the frames a
+    registration reads, each of which runs one program at a time.  The
+    dispatcher caps the element-domain worker budget there.  None/absent
+    means "unknown": no cap.
+    """
+    est = getattr(op, "op_concurrency", None)
+    if est is None:
+        return None
+    if callable(est):
+        est = est()
+    return int(est) if est is not None else None
+
+
 def element_costs_from(op, n: int) -> Optional[list]:
     """Per-element cost priors from the operator's history, if it keeps any.
 

@@ -397,6 +397,18 @@ class RegistrationOperator:
         by the dispatcher (``engine/cost.py:CROSS_STEAL_MIN_IMBALANCE``)."""
         return self.telemetry.imbalance() if self.telemetry.calls >= 2 else None
 
+    @property
+    def op_concurrency(self) -> Optional[int]:
+        """Applications that can run at the same time: the accelerators
+        that hold the frames, each running one program at a time.  None
+        for frames on the CPU, whose client runs programs from several
+        threads at once.  Read by the dispatcher, which caps its worker
+        budget there (``engine/cost.py:worker_budget``)."""
+        devices = getattr(self.registrar.frames, "devices", None)
+        if devices is None:
+            return None
+        return len({d for d in devices() if d.platform != "cpu"}) or None
+
     def prime(self, seconds_per_call: float) -> None:
         """Seed the cost estimate before the first application (e.g. from
         the function-A preprocessing stage, whose per-pair cost is the same

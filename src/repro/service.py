@@ -73,9 +73,11 @@ from repro.core.engine import (
     dispatch as cost_dispatch,
     get_telemetry,
     op_batchable_from,
+    op_concurrency_from,
     pool_aware_workers,
     release_telemetry,
     scan as engine_scan,
+    worker_budget,
 )
 from repro.core.registration import (
     RegElement,
@@ -226,9 +228,10 @@ class SeriesResult:
 class _FrameStore:
     """Frame access by *global* series index with O(1) residency.
 
-    Registrar-compatible (``shape`` + integer indexing), so function B can
-    keep addressing ``frames[a.i]`` / ``frames[b.k]`` by global index while
-    the session retains only the frames an incremental scan can touch:
+    Registrar-compatible (``shape``, integer indexing and ``devices()``,
+    as on a ``jax.Array``), so function B can keep addressing
+    ``frames[a.i]`` / ``frames[b.k]`` by global index while the session
+    retains only the frames an incremental scan can touch:
     frame 0 and the chunk boundary (everything else is evicted after its
     chunk has been folded in).  Touching an evicted frame is a protocol
     bug, not a recoverable condition — it raises with the index.
@@ -263,6 +266,10 @@ class _FrameStore:
 
     def last(self) -> Optional[jax.Array]:
         return self._frames.get(self._n - 1)
+
+    def devices(self) -> set:
+        """The devices that hold the resident frames."""
+        return set().union(*(f.devices() for f in self._frames.values()))
 
     def append_chunk(self, chunk: jax.Array) -> None:
         for i in range(chunk.shape[0]):
@@ -590,16 +597,23 @@ class SeriesSession:
         cross_steal = cfg.cross_steal
         with self.pool.tenant():
             if backend_used is None:
+                workers = pool_aware_workers(self.pool, cfg.workers)
+                concurrency = op_concurrency_from(op)
                 with spans.span("scan.dispatch"):
                     d = cost_dispatch(
                         n_new, domain="element",
                         op_cost=op.op_cost_estimate,
-                        workers=pool_aware_workers(self.pool, cfg.workers),
+                        workers=workers,
                         op_imbalance=op.op_imbalance_estimate,
                         pool_occupancy=self.pool.occupancy(),
                         op_batchable=op_batchable_from(op),
                         devices=self._devices,
+                        op_concurrency=concurrency,
                     )
+                with spans.span("scan.plan", backend=d.backend,
+                                workers=worker_budget(workers, concurrency),
+                                elements=n_new):
+                    pass
                 # Execute exactly what the dispatcher decided (its circuit,
                 # segment and thread counts — unless the config pins them).
                 self._dispatch = d

@@ -10,6 +10,7 @@ from repro.core.engine import (
     EXPENSIVE_OP_COST,
     dispatch,
     measure_op_cost,
+    op_concurrency_from,
     plan_cache,
     register_backend,
     scan,
@@ -53,6 +54,57 @@ def test_cheap_element_op_stays_element():
 def test_single_worker_never_worksteals():
     d = dispatch(64, domain="element", op_cost=10.0, workers=1)
     assert d.backend == "element"
+
+
+class _Reports:
+    """An operator that reports ``op_concurrency`` as given."""
+
+    def __init__(self, concurrency):
+        self.op_concurrency = concurrency
+
+
+@pytest.mark.parametrize("op,c", [
+    (_Reports(1), 1),
+    (_Reports(lambda: 1), 1),
+    (_Reports(2), 2),
+    (_Reports(lambda: 4), 4),
+    (object(), None),
+], ids=["int1", "callable1", "int2", "callable4", "absent"])
+def test_op_concurrency_caps_element_workers(op, c):
+    """The element-domain worker budget stops at the operator's concurrency:
+    at 1 no parallel phase applies (the work-optimal chain), above it the
+    parallel backends run with at most that many threads, and an operator
+    that says nothing gets exactly the decision it got before."""
+    assert op_concurrency_from(op) == c
+    base = dict(domain="element", op_cost=0.1, workers=64)
+    d = dispatch(8, **base, op_concurrency=op_concurrency_from(op))
+    if c is None:
+        assert d == dispatch(8, **base)
+        assert d.backend == "worksteal"
+    elif c == 1:
+        assert (d.backend, d.algorithm) == ("element", "sequential")
+    else:
+        assert d.backend in ("worksteal", "hierarchical")
+        assert d.num_threads <= c
+
+
+def test_one_executor_operator_runs_the_seeded_chain():
+    """Through ``scan``: an expensive operator whose applications cannot run
+    at once folds a seeded suffix in one application per element."""
+    calls = []
+
+    class OneDevice:
+        op_cost_estimate = 1.0
+        op_concurrency = 1
+
+        def __call__(self, a, b):
+            calls.append((a, b))
+            return a + b
+
+    vals = [float(i) for i in range(1, 9)]
+    ys = scan(OneDevice(), vals, seed=10.0, workers=8)
+    np.testing.assert_allclose(ys, 10.0 + np.cumsum(vals))
+    assert len(calls) == len(vals)
 
 
 def test_measure_op_cost_orders_regimes():

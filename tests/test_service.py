@@ -497,3 +497,40 @@ def test_concurrent_sessions_on_shared_pool():
         pool.shutdown()
     finally:
         service.register_pair = orig
+
+
+def test_one_device_operator_runs_the_seeded_chain(monkeypatch):
+    """An operator whose applications share one device (``op_concurrency``
+    1) folds every seeded feed in as the work-optimal chain: one function-B
+    application per new element, each the pair (0, k), with the
+    deformations of the default dispatch."""
+    from repro.core.registration import RegistrationOperator, SeriesRegistrar
+    from repro.data.images import make_series
+
+    frames, _ = make_series(jax.random.PRNGKey(11), 12, size=64, noise=0.12)
+    store = _FrameStore()
+    store.append_chunk(frames[:2])
+    # Frames on the CPU: its client runs programs from several threads.
+    assert RegistrationOperator(SeriesRegistrar(store)).op_concurrency is None
+    assert RegistrationOperator(SeriesRegistrar(frames)).op_concurrency is None
+
+    def run():
+        with open_series(repro.RegisterSeriesConfig()) as s:
+            for i in range(0, 12, 4):
+                s.feed(frames[i:i + 4])
+            return s, s.result()
+
+    _, ref = run()
+    monkeypatch.setattr(RegistrationOperator, "op_concurrency",
+                        property(lambda self: 1))
+    s, got = run()
+    assert s._backend_used == "element"
+    assert got.dispatch.algorithm == "sequential"
+    seeded = s.summaries[1:]
+    assert [c.ops for c in seeded] == [c.n_elems for c in seeded] == [4, 4]
+    assert [(e.i, e.k) for e in got.elements] == [(0, k) for k in range(1, 12)]
+    np.testing.assert_allclose(
+        np.asarray(got.deformations["shift"]),
+        np.asarray(ref.deformations["shift"]),
+        atol=5e-3,
+    )

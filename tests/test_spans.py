@@ -73,6 +73,33 @@ def test_session_spans_under_the_dispatcher(frames, tmp_path):
     _check(events, sid, res, EVERY | {"repro.scan.dispatch"})
 
 
+@pytest.mark.parametrize("concurrency", [None, 1])
+def test_plan_counter_once_per_refined_feed(frames, tmp_path, monkeypatch,
+                                            concurrency):
+    """``repro.scan.plan`` follows each dispatch with the plan that ran:
+    the backend, the worker budget after the operator's concurrency, and
+    the elements scanned."""
+    from repro.core.registration import RegistrationOperator
+
+    if concurrency is not None:
+        monkeypatch.setattr(RegistrationOperator, "op_concurrency",
+                            property(lambda self: concurrency))
+    events, sid, res = _traced(frames, tmp_path)
+    _check(events, sid, res, EVERY | {"repro.scan.dispatch", "repro.scan.plan"})
+    plans = [(s, e, st) for n, s, e, st in events if n == "repro.scan.plan"]
+    dispatches = [(s, e) for n, s, e, _ in events if n == "repro.scan.dispatch"]
+    assert len(plans) == len(dispatches) == CHUNKS
+    for (s, _, _), (_, d_end) in zip(plans, dispatches):
+        assert s >= d_end
+    stats = [st for *_, st in plans]
+    assert [st["elements"] for st in stats] == [CHUNK - 1] + [CHUNK] * (CHUNKS - 1)
+    assert stats[-1]["backend"] == res.backend
+    assert all(st["workers"] >= 1 for st in stats)
+    if concurrency == 1:
+        assert {st["backend"] for st in stats} == {"element"}
+        assert {st["workers"] for st in stats} == {1}
+
+
 @pytest.mark.parametrize("backend,extra", [
     ("worksteal", {"num_threads": 2}),
     ("hierarchical", {"num_segments": 2, "num_threads": 1}),
