@@ -15,7 +15,9 @@ algorithm depends on the operator-cost regime —
   when there are executors to run its phases at once.  The worker budget
   is capped at the operator's own concurrency (``op_concurrency``: the
   devices that hold its data); at one, the extra applications of the
-  parallel phases buy nothing, and the work-optimal chain runs instead.
+  parallel phases buy nothing, and the work-optimal chain runs instead;
+  at several, the hierarchical backend gives each device one segment and
+  one worker (``hierarchical``).
 * in between, per-element execution (``element``) avoids the batching
   overhead that vectorization pays for operators that do not fuse.
 
@@ -224,8 +226,10 @@ def dispatch(
     ``op_concurrency``: how many of the operator's applications can run
     at the same time (``op_concurrency_from``: the devices that hold its
     data).  Caps the element-domain worker budget; at 1 no parallel phase
-    applies and a seeded scan runs the work-optimal chain.  None (the
-    operator does not say) leaves the budget as it is.
+    applies and a seeded scan runs the work-optimal chain; above 1 an
+    expensive operator runs ``hierarchical`` with one segment and one
+    worker per device.  None (the operator does not say) leaves the
+    budget as it is.
     """
     if n <= 1:
         return Dispatch("element" if domain == "element" else "vector",
@@ -283,6 +287,31 @@ def dispatch(
                 reason=f"pool saturated (occupancy {pool_occupancy:.2f} >= "
                        f"{POOL_BUSY_OCCUPANCY}) -> work-optimal sequential "
                        "chain instead of queueing",
+            )
+        if (
+            cost >= EXPENSIVE_OP_COST
+            and op_concurrency is not None
+            and op_concurrency > 1
+            and w > 1
+            and n >= 2 * op_concurrency
+        ):
+            # The operator's data lies on several devices, one program at
+            # a time on each: the paper's two levels with a device as the
+            # node — one segment per device, one worker each, stealing
+            # across the segment borders.
+            s = op_concurrency
+            cross = (
+                op_imbalance is None
+                or op_imbalance >= CROSS_STEAL_MIN_IMBALANCE
+            )
+            return Dispatch(
+                "hierarchical", "ladner_fischer",
+                num_segments=s, num_threads=1,
+                strategy="reduce_then_scan",
+                cross_steal=cross,
+                reason=f"expensive op ({cost:.2e}s) on {s} devices -> "
+                       "hierarchical reduce-then-scan, one worker per "
+                       f"device; cross-segment={'on' if cross else 'off'}",
             )
         if cost >= EXPENSIVE_OP_COST and w >= HIER_MIN_WORKERS and n >= 2 * w:
             # Paper §4.2: at nodes × cores scale, two-level reduce-then-scan —

@@ -191,27 +191,46 @@ def _exec_hier_element(
         static_reduce,
         stealing_reduce,
     )
-    from .telemetry import OpTelemetry, element_costs_from
+    from .telemetry import (
+        OpTelemetry,
+        element_costs_from,
+        op_homes_from,
+        op_worker_from,
+    )
 
     global last_stats
     if pool is None:
         pool = get_default_pool()
     n = len(xs)
-    s = max(1, min(num_segments, n))
     t = max(1, num_threads)
 
-    # Ahead-of-time segment sizing: when the operator carries per-element
-    # cost history (RegistrationOperator telemetry, or an explicit
-    # ``element_costs``), size segments to equal *cost* instead of equal
-    # count, so a known-expensive stretch starts with fewer elements.
-    costs = element_costs if element_costs is not None else (
-        element_costs_from(op, n)
-    )
-    rebalanced = costs is not None and len(costs) == n and s > 1
-    if rebalanced:
-        bounds = rebalance_boundaries(list(costs), segment_bounds(n, s))
+    # Executor affinity: an operator that says which executor (chip) holds
+    # each element's data gets one segment per run of one executor, in
+    # place of ``num_segments``; that executor's worker reduces and
+    # applies it, so only a cross-segment steal moves data.
+    homes = op_homes_from(op, xs)
+    rebalanced = False
+    if homes is not None:
+        bounds = _home_runs(homes)
+        s = len(bounds)
+        owners = [homes[lo] for lo, _ in bounds]
     else:
-        bounds = segment_bounds(n, s)
+        s = max(1, min(num_segments, n))
+        owners = list(range(s))
+        # Ahead-of-time segment sizing: when the operator carries
+        # per-element cost history (RegistrationOperator telemetry, or an
+        # explicit ``element_costs``), size segments to equal *cost*
+        # instead of equal count, so a known-expensive stretch starts with
+        # fewer elements.
+        costs = element_costs if element_costs is not None else (
+            element_costs_from(op, n)
+        )
+        rebalanced = costs is not None and len(costs) == n and s > 1
+        if rebalanced:
+            bounds = rebalance_boundaries(list(costs), segment_bounds(n, s))
+        else:
+            bounds = segment_bounds(n, s)
+    seg_workers = [op_worker_from(op, w) for w in owners]
     phase: Dict[str, float] = {}
     ops_count = 0
 
@@ -226,19 +245,21 @@ def _exec_hier_element(
     cross = cross and starts is not None
 
     # --- phase 1: per-segment (stealing) reduction, segments concurrent.
-    def reduce_segment(lo: int, hi: int):
+    def reduce_segment(i: int):
+        lo, hi = bounds[i]
+        sop = seg_workers[i]
         seg = list(xs[lo : hi + 1])
         ln = hi - lo + 1
         t_eff = min(t, ln // 2)
         if t_eff >= 2:
             fn = stealing_reduce if stealing else static_reduce
-            partials, st = fn(op, seg, t_eff, pool=pool)
+            partials, st = fn(sop, seg, t_eff, pool=pool)
             intervals = [(lo + a, lo + b) for a, b in st.boundaries]
             reduce_ops = st.total_ops
         else:
             acc = seg[0]
             for item in seg[1:]:
-                acc = op(acc, item)
+                acc = sop(acc, item)
             partials, st, intervals = [acc], None, [(lo, hi)]
             reduce_ops = ln - 1
         # Inclusive scan over the thread partials (T is small) — its last
@@ -246,7 +267,7 @@ def _exec_hier_element(
         # the per-interval applies in phase 3.
         pscan = [partials[0]]
         for p in partials[1:]:
-            pscan.append(op(pscan[-1], p))
+            pscan.append(sop(pscan[-1], p))
         return pscan, intervals, st, reduce_ops + len(pscan) - 1
 
     if cross:
@@ -264,9 +285,24 @@ def _exec_hier_element(
             OpTelemetry(name=f"hier_seg{i}", ema_alpha=0.4) for i in range(s)
         ]
 
+        def steal_counter(i: int):
+            """A zero-length ``repro.scan.steal`` span at each element
+            segment ``i`` claims beyond its border, with the bytes its
+            worker's application copied between chips, tagged with the
+            operator's session (the steal runs on a pool thread)."""
+            def on_steal(_idx: int, side: str) -> None:
+                src = owners[i - 1] if side == "L" else owners[i + 1]
+                with span("scan.steal", getattr(op, "session", None),
+                          from_chip=src, to_chip=owners[i],
+                          elements=1,
+                          bytes=getattr(seg_workers[i], "last_moved", 0)):
+                    pass
+            return on_steal
+
         def reduce_segment_cross(i: int):
+            sop = seg_workers[i]
             partials, st = stealing_reduce(
-                op,
+                sop,
                 xs,
                 tcounts[i],
                 starts=starts[offs[i] : offs[i + 1]],
@@ -277,11 +313,12 @@ def _exec_hier_element(
                     seg_tel[i + 1].estimate if i < s - 1 else None,
                 ),
                 record=seg_tel[i].record,
+                on_steal=steal_counter(i),
                 pool=pool,
             )
             pscan = [partials[0]]
             for p in partials[1:]:
-                pscan.append(op(pscan[-1], p))
+                pscan.append(sop(pscan[-1], p))
             return pscan, st.boundaries, st, st.total_ops + len(pscan) - 1
 
     t0 = time.perf_counter()
@@ -295,62 +332,69 @@ def _exec_hier_element(
             # spans.
             bounds = [(r[1][0][0], r[1][-1][1]) for r in seg_results]
         elif s == 1:
-            seg_results = [reduce_segment(*bounds[0])]
+            seg_results = [reduce_segment(0)]
         else:
             seg_results = pool.run_tasks(
-                [functools.partial(reduce_segment, lo, hi)
-                 for lo, hi in bounds],
+                [functools.partial(reduce_segment, i) for i in range(s)],
                 label="hier_reduce",
             )
     phase["reduce"] = time.perf_counter() - t0
     for _pscan, _intervals, _st, seg_ops in seg_results:
         ops_count += seg_ops
 
-    # --- phase 2: small cross-segment scan over the S totals.
+    # --- phase 2: small cross-segment scan over the S totals, giving each
+    # segment its base (the exclusive prefix, seed included).
     t0 = time.perf_counter()
     totals = [r[0][-1] for r in seg_results]
-    if s > 1:
+    bases: List[Any] = [seed]
+    if s > 1 and seed is not None:
+        # Seeded: a chain from the seed, base_i = base_{i-1} . total_{i-1}:
+        # S - 1 applications, where a circuit and then the seed combines
+        # would take the circuit's work plus S - 1.  Every one starts from
+        # the seed's first element (frame 0 of a series).
+        with span("scan.phase2"):
+            for tot in totals[:-1]:
+                bases.append(op(bases[-1], tot))
+        ops_count += s - 1
+        rounds = s - 1
+    elif s > 1:
         if plan is None or plan.n != s or plan.exclusive:
             plan = get_plan("ladner_fischer", s)
         with span("scan.phase2"):
             scanned, _ = exec_element(op, plan, totals)
         ops_count += plan.work()
+        bases += scanned[:-1]
+        rounds = plan.num_rounds() + 1
     else:
-        scanned = totals
-    total = scanned[-1]
+        rounds = 0
     phase["global"] = time.perf_counter() - t0
 
     # --- phase 3: seeded per-interval applies, all intervals concurrent.
     t0 = time.perf_counter()
     out: List[Any] = [None] * n
-    jobs: List[Tuple[int, int, Any]] = []
+    jobs: List[Tuple[int, int, Any, int]] = []
 
     def apply_interval(job):
-        lo, hi, acc = job
+        lo, hi, acc, i = job
+        sop = seg_workers[i]
         k = 0
         for idx in range(lo, hi + 1):
-            acc = xs[idx] if acc is None else op(acc, xs[idx])
+            acc = xs[idx] if acc is None else sop(acc, xs[idx])
             out[idx] = acc
             k += 1
         return k - (1 if job[2] is None else 0)
 
     with span("scan.phase3"):
         for i, (pscan, intervals, _st, _ops) in enumerate(seg_results):
-            if i == 0:
-                base = seed
-            elif seed is None:
-                base = scanned[i - 1]
-            else:
-                base = op(seed, scanned[i - 1])
-                ops_count += 1  # seed combines execute the operator
+            base = bases[i]
             for j, (lo, hi) in enumerate(intervals):
                 if j == 0:
                     sj = base
                 else:
                     sj = (pscan[j - 1] if base is None
-                          else op(base, pscan[j - 1]))
+                          else seg_workers[i](base, pscan[j - 1]))
                     ops_count += 0 if base is None else 1
-                jobs.append((lo, hi, sj))
+                jobs.append((lo, hi, sj, i))
         if len(jobs) == 1:
             ops_count += apply_interval(jobs[0])
         else:
@@ -366,7 +410,7 @@ def _exec_hier_element(
         num_segments=s,
         threads_per_segment=t,
         segment_bounds=bounds,
-        intervals=[(lo, hi) for lo, hi, _ in jobs],
+        intervals=[(lo, hi) for lo, hi, _, _ in jobs],
         steal_stats=[r[2] for r in seg_results],
         phase_seconds=phase,
         total_ops=ops_count,
@@ -376,9 +420,19 @@ def _exec_hier_element(
             for r in seg_results
         ] if cross else [0] * s,
         rebalanced=rebalanced,
-        phase2_rounds=(plan.num_rounds() + 1) if s > 1 else 0,
+        phase2_rounds=rounds,
     )
-    return out, total
+    return out, out[-1]
+
+
+def _home_runs(homes: Sequence[int]) -> List[Tuple[int, int]]:
+    """Inclusive bounds of the runs of equal executor in ``homes``."""
+    bounds, lo = [], 0
+    for j in range(1, len(homes) + 1):
+        if j == len(homes) or homes[j] != homes[lo]:
+            bounds.append((lo, j - 1))
+            lo = j
+    return bounds
 
 
 # ---------------------------------------------------------------------------

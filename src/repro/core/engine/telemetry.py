@@ -209,6 +209,22 @@ def op_concurrency_from(op) -> Optional[int]:
     return int(est) if est is not None else None
 
 
+def op_homes_from(op, xs) -> Optional[list]:
+    """Which executor holds each element's data, as a list of executor
+    indices, or None when the operator does not say (``op_homes``).  The
+    hierarchical backend makes each run of one executor a segment and
+    gives it that executor's worker."""
+    homes = getattr(op, "op_homes", None)
+    return None if homes is None else homes(xs)
+
+
+def op_worker_from(op, executor: int):
+    """The operator as executor ``executor``'s worker applies it
+    (``op_worker``, e.g. one worker per chip), or the operator itself."""
+    bind = getattr(op, "op_worker", None)
+    return op if bind is None else bind(executor)
+
+
 def element_costs_from(op, n: int) -> Optional[list]:
     """Per-element cost priors from the operator's history, if it keeps any.
 

@@ -380,6 +380,12 @@ class RegistrationOperator:
                 "(only 2-D gathers are supported). Leave fused unset."
             )
         self.fused = bool(fused) and fused_ncc_eligible((h, w), tile)
+        # Frames placed across chips (a store with ``on``/``home``): each
+        # application runs on one of them, ordered by device id.
+        frames = registrar.frames
+        devs = frames.devices() if hasattr(frames, "on") else ()
+        self._chips = (sorted(devs, key=lambda d: d.id) if len(devs) > 1
+                       else None)
         self._count_lock = threading.Lock()
         self._elem_prior: Optional[list] = None
         self._elem_obs: dict = {}
@@ -400,14 +406,35 @@ class RegistrationOperator:
     @property
     def op_concurrency(self) -> Optional[int]:
         """Applications that can run at the same time: the accelerators
-        that hold the frames, each running one program at a time.  None
-        for frames on the CPU, whose client runs programs from several
-        threads at once.  Read by the dispatcher, which caps its worker
-        budget there (``engine/cost.py:worker_budget``)."""
+        that hold the frames, each running one program at a time, or the
+        devices the frames were placed across.  None for frames on one CPU
+        device, whose client runs programs from several threads at once.
+        Read by the dispatcher, which caps its worker budget there
+        (``engine/cost.py:worker_budget``) and gives each device a worker
+        when there are several."""
         devices = getattr(self.registrar.frames, "devices", None)
         if devices is None:
             return None
-        return len({d for d in devices() if d.platform != "cpu"}) or None
+        devs = devices()
+        return (len({d for d in devs if d.platform != "cpu"})
+                or (len(devs) if len(devs) > 1 else None))
+
+    def op_homes(self, elems) -> Optional[list]:
+        """The chip (an index into the frames' devices) that holds each
+        element's frame ``k``, or None when the frames are on one device.
+        The hierarchical backend makes each run of one chip a segment."""
+        if self._chips is None:
+            return None
+        frames = self.registrar.frames
+        return [self._chips.index(frames.home(e.k)) for e in elems]
+
+    def op_worker(self, chip: int) -> "_ChipWorker":
+        """This operator as the worker of ``chip`` applies it: every
+        application runs on that chip, and frames held elsewhere are
+        copied there (a cross-chip steal moves its element's frame)."""
+        if self._chips is None:
+            return _ChipWorker(self, None, chip)
+        return _ChipWorker(self, self._chips[chip % len(self._chips)], chip)
 
     def prime(self, seconds_per_call: float) -> None:
         """Seed the cost estimate before the first application (e.g. from
@@ -464,14 +491,33 @@ class RegistrationOperator:
         return ncc_distance(ref, tmpl, guess)
 
     def __call__(self, a: RegElement, b: RegElement) -> RegElement:
+        return self._apply(a, b, None)[0]
+
+    def _frames_on(self, i: int, k: int, device):
+        """Frames ``i`` and ``k`` on ``device`` (as stored when the frames
+        are on one device), and the frame bytes copied there."""
+        frames = self.registrar.frames
+        if device is None:
+            return frames[i], frames[k], 0
+        ref, moved_ref = frames.on(i, device)
+        tmpl, moved_tmpl = frames.on(k, device)
+        return ref, tmpl, moved_ref + moved_tmpl
+
+    def _apply(self, a: RegElement, b: RegElement, device):
+        """One application of function B, and the frame bytes it copied
+        between chips."""
         import time
 
-        with span("fn_b", self.session, i=a.i, k=b.k):
+        reg = self.registrar
+        if self._chips is not None and device is None and reg.refine:
+            # Outside a worker: the chip that holds frame k.
+            device = reg.frames.home(b.k)
+        chip = 0 if device is None else self._chips.index(device)
+        with span("fn_b", self.session, i=a.i, k=b.k, chip=chip):
             t0 = time.perf_counter()
-            reg = self.registrar
             sig = (
                 tuple(reg.frames.shape[1:]), reg.cfg, reg.refine,
-                self.skip_tol is not None, self.fused,
+                self.skip_tol is not None, self.fused, device,
             )
             # Cold until the first call under this signature *completes*:
             # concurrent calls that start while the compile is in flight all
@@ -489,17 +535,19 @@ class RegistrationOperator:
             elem_idxs = [e.k - 1 for e in (a, b) if e.k - e.i == 1]
             try:
                 assert a.k == b.i, f"non-adjacent elements {a.i, a.k} . {b.i, b.k}"
-                guess = compose(a.deformation, b.deformation)
+                da, db = a.deformation, b.deformation
+                if device is not None:
+                    da, db = jax.device_put((da, db), device)
+                guess = compose(da, db)
                 if not reg.refine:
-                    return RegElement(guess, a.i, b.k)
+                    return RegElement(guess, a.i, b.k), 0
+                ref, tmpl, moved = self._frames_on(a.i, b.k, device)
                 if self.skip_tol is not None:
-                    dist = self._guess_distance(
-                        reg.frames[a.i], reg.frames[b.k], guess
-                    )
+                    dist = self._guess_distance(ref, tmpl, guess)
                     if float(dist) < self.skip_tol:
-                        return RegElement(guess, a.i, b.k)
-                res = register_pair(reg.frames[a.i], reg.frames[b.k], guess, reg.cfg)
-                return RegElement(res.deformation, a.i, b.k)
+                        return RegElement(guess, a.i, b.k), moved
+                res = register_pair(ref, tmpl, guess, reg.cfg)
+                return RegElement(res.deformation, a.i, b.k), moved
             finally:
                 dt = time.perf_counter() - t0
                 self.telemetry.record(dt, compile=cold)
@@ -514,3 +562,20 @@ class RegistrationOperator:
                             self._elem_obs[j] = (
                                 dt if prev is None else 0.5 * prev + 0.5 * dt
                             )
+
+
+class _ChipWorker:
+    """A :class:`RegistrationOperator` bound to one chip
+    (``op_worker``).  ``last_moved`` holds the frame bytes its latest
+    application copied between chips: the hierarchical backend reads it
+    after a cross-segment steal."""
+
+    def __init__(self, op: RegistrationOperator, device, chip: int):
+        self.op = op
+        self.device = device
+        self.chip = chip
+        self.last_moved = 0
+
+    def __call__(self, a: RegElement, b: RegElement) -> RegElement:
+        out, self.last_moved = self.op._apply(a, b, self.device)
+        return out

@@ -225,6 +225,7 @@ def stealing_reduce(
     outer_rates: Tuple[Optional[Callable[[], Optional[float]]],
                        Optional[Callable[[], Optional[float]]]] = (None, None),
     record: Optional[Callable[[float], None]] = None,
+    on_steal: Optional[Callable[[int, str], None]] = None,
     pool=None,
 ) -> Tuple[List[Any], StealStats]:
     """Phase 1 of reduce-then-scan with work stealing (Algorithm 1).
@@ -250,6 +251,9 @@ def stealing_reduce(
     ``record``
         per-application duration callback feeding this segment's own rate
         EMA, so *its* neighbours can make the symmetric choice.
+    ``on_steal``
+        called with the claimed index and the side (``"L"``/``"R"``)
+        after the application that folds in each cross-segment steal.
     ``pool``
         scheduler the worker tasks run on (shared process-wide
         :class:`~repro.runtime.scheduler.WorkerPool` by default) — this
@@ -345,12 +349,13 @@ def stealing_reduce(
                 record(dt)
             # Cross-segment steal = a claim from a shared outer gap that
             # landed beyond the static border (in the neighbour's half).
-            if (tid == 0 and d == "L" and left.border is not None
-                    and idx < left.border):
+            if ((tid == 0 and d == "L" and left.border is not None
+                    and idx < left.border)
+                    or (tid == t - 1 and d == "R" and right.border is not None
+                        and idx >= right.border)):
                 st.cross_steals += 1
-            elif (tid == t - 1 and d == "R" and right.border is not None
-                    and idx >= right.border):
-                st.cross_steals += 1
+                if on_steal is not None:
+                    on_steal(idx, d)
         results[tid] = res
         st.finish_time = clock() - t0
 

@@ -59,6 +59,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core.deformation import (
@@ -79,6 +80,7 @@ from repro.core.engine import (
     scan as engine_scan,
     worker_budget,
 )
+from repro.core.engine.sharded import AXIS, default_mesh
 from repro.core.registration import (
     RegElement,
     RegistrationConfig,
@@ -235,10 +237,18 @@ class _FrameStore:
     frame 0 and the chunk boundary (everything else is evicted after its
     chunk has been folded in).  Touching an evicted frame is a protocol
     bug, not a recoverable condition — it raises with the index.
+
+    Across chips each frame has a *home* (the chip that registers it) and
+    may have copies on other chips: frame 0 on every chip, a halo frame
+    at each chip boundary (placed by the session), and whatever function
+    B fetches with :meth:`on` (counted in ``moved_bytes``).
     """
 
     def __init__(self):
         self._frames: Dict[int, jax.Array] = {}
+        self._copies: Dict[tuple, jax.Array] = {}    # (index, device) -> copy
+        self._copy_lock = threading.Lock()
+        self.moved_bytes = 0     # bytes function B copied between chips
         self._n = 0
         self._hw: tuple = ()
 
@@ -268,24 +278,95 @@ class _FrameStore:
         return self._frames.get(self._n - 1)
 
     def devices(self) -> set:
-        """The devices that hold the resident frames."""
+        """The devices that hold the resident frames (homes, not copies)."""
         return set().union(*(f.devices() for f in self._frames.values()))
 
+    def resident(self, i, device) -> bool:
+        """Whether frame ``i`` is on ``device``, as its home or a copy."""
+        f = self._frames.get(int(i))
+        return f is not None and (device in f.devices()
+                                  or (int(i), device) in self._copies)
+
+    def home(self, i) -> Any:
+        """The device that holds frame ``i``'s home array."""
+        return next(iter(self[i].devices()))
+
+    def place(self, i, device) -> int:
+        """Make frame ``i`` resident on ``device`` ahead of its use;
+        returns the bytes copied."""
+        f = self[i]
+        key = (int(i), device)
+        with self._copy_lock:
+            if device in f.devices() or key in self._copies:
+                return 0
+            self._copies[key] = jax.device_put(f, device)
+        return int(f.nbytes)
+
+    def on(self, i, device) -> tuple:
+        """Frame ``i`` on ``device``, and the bytes copied to get it there:
+        its home array, a placed copy, or a new copy that stays resident
+        (counted in ``moved_bytes``)."""
+        f = self[i]
+        if device in f.devices():
+            return f, 0
+        key = (int(i), device)
+        with self._copy_lock:
+            c = self._copies.get(key)
+            if c is not None:
+                return c, 0
+            c = self._copies[key] = jax.device_put(f, device)
+            self.moved_bytes += int(f.nbytes)
+        return c, int(f.nbytes)
+
     def append_chunk(self, chunk: jax.Array) -> None:
-        for i in range(chunk.shape[0]):
-            self._frames[self._n + i] = chunk[i]
-        self._n += int(chunk.shape[0])
-        self._hw = tuple(chunk.shape[1:])
+        self.append_frames([chunk[i] for i in range(chunk.shape[0])])
+
+    def append_frames(self, frames: List[jax.Array]) -> None:
+        """Append frames that already sit on their home devices."""
+        for i, f in enumerate(frames):
+            self._frames[self._n + i] = f
+        self._n += len(frames)
+        self._hw = tuple(frames[0].shape)
 
     def evict(self, keep) -> None:
         keep = set(keep)
         self._frames = {i: f for i, f in self._frames.items() if i in keep}
+        with self._copy_lock:
+            self._copies = {k: f for k, f in self._copies.items()
+                            if k[0] in keep}
 
     def restore(self, n: int, frames: Dict[int, jax.Array]) -> None:
         self._n = n
         self._frames = dict(frames)
+        self._copies = {}
         if frames:
             self._hw = tuple(next(iter(frames.values())).shape)
+
+
+def _shard_rows(a: jax.Array) -> List[jax.Array]:
+    """The blocks of an array split along its first axis, in order, each
+    on its own device."""
+    shards = sorted(a.addressable_shards, key=lambda s: s.index[0].start or 0)
+    return [s.data for s in shards]
+
+
+def _fn_a_shards(mesh: Mesh, cfg: RegistrationConfig):
+    """Function A sharded by pair: one program over ``mesh`` in which each
+    chip registers its block of frames against their predecessors (its
+    halo frame, then its own frames) as one vmapped cohort.  There is no
+    collective, so each chip's while loops stop on their own.  Compiled
+    as ``jit_fn_a_shards``, the name the device trace shows."""
+    spec = PartitionSpec(mesh.axis_names[0])
+
+    def shard(halo, tmps):
+        refs = jnp.concatenate([halo, tmps[:-1]], axis=0)
+        return jax.vmap(lambda r, t: register_pair(r, t, None, cfg))(refs, tmps)
+
+    def fn_a_shards(halo, tmps):
+        return jax.shard_map(shard, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec, check_vma=False)(halo, tmps)
+
+    return fn_a_shards
 
 
 @dataclasses.dataclass
@@ -377,12 +458,16 @@ class SeriesSession:
             else jax.device_count(),
             jax.device_count(),
         ))
-        if self._devices >= SHARDED_MIN_DEVICES:
-            from repro.core.engine.sharded import default_mesh
-
-            self._mesh = default_mesh(self._devices)
-        else:
-            self._mesh = None
+        # Frames across chips: each chunk is split by frame over the same
+        # devices, and each chip registers its own frames.
+        mesh = default_mesh(self._devices) if self._devices > 1 else None
+        self._mesh = mesh if self._devices >= SHARDED_MIN_DEVICES else None
+        self._chips = tuple(mesh.devices.flat) if mesh else None
+        self._chip_sharding = (
+            NamedSharding(mesh, PartitionSpec(AXIS)) if mesh else None
+        )
+        self._steals = 0
+        self._placed_bytes = 0
         self._pre_seconds = 0.0
         self._pre_pairs = 0
         self._feed_lock = threading.Lock()
@@ -405,6 +490,16 @@ class SeriesSession:
     @property
     def summaries(self) -> List[_ChunkSummary]:
         return list(self._summaries)
+
+    def traffic(self) -> Dict[str, int]:
+        """Totals across chips over the session's life: ``steals``, the
+        elements function B's workers claimed across a chip boundary;
+        ``moved_bytes``, the frame bytes function B copied between chips;
+        ``placed_bytes``, the frame bytes ``feed`` placed (the chunk's
+        layout, halo frames, frame 0 on every chip)."""
+        return {"steals": self._steals,
+                "moved_bytes": self._store.moved_bytes,
+                "placed_bytes": self._placed_bytes}
 
     def _check_open(self) -> None:
         if self._closed:
@@ -434,27 +529,133 @@ class SeriesSession:
                 jax.block_until_ready(chunk)
             if chunk.shape[0] == 0:
                 return self
-            prev_last = self._store.last()
-            refs = (
-                chunk[:-1] if prev_last is None
-                else jnp.concatenate([prev_last[None], chunk[:-1]], axis=0)
-            )
-            tmps = chunk if prev_last is not None else chunk[1:]
-            new_elems: List[RegElement] = []
-            lanes = int(refs.shape[0])
-            if lanes:
-                pre_before = self._timings["preprocess"]
-                with self._stage("preprocess", "fn_a", lanes=lanes):
-                    new_elems = self._register_pairs(refs, tmps)
-                self._pre_pairs += lanes
-                self._pre_seconds += self._timings["preprocess"] - pre_before
-            self._store.append_chunk(chunk)
+            new_elems = (self._fold_chunk_chips(chunk) if self._chips
+                         else self._fold_chunk(chunk))
             if new_elems:
                 self._scan_suffix(new_elems)
             # O(1) residency: only frame 0 and the boundary frame can be
             # touched by future feeds.
             self._store.evict({0, self._store.n - 1})
         return self
+
+    def _fold_chunk(self, chunk) -> List[RegElement]:
+        """Function A on one device: the chunk's pairs, with the pair that
+        spans the previous chunk's boundary, as one vmapped cohort; then
+        the chunk's frames join the store."""
+        prev_last = self._store.last()
+        refs = (
+            chunk[:-1] if prev_last is None
+            else jnp.concatenate([prev_last[None], chunk[:-1]], axis=0)
+        )
+        tmps = chunk if prev_last is not None else chunk[1:]
+        new_elems: List[RegElement] = []
+        lanes = int(refs.shape[0])
+        if lanes:
+            pre_before = self._timings["preprocess"]
+            with self._stage("preprocess", "fn_a", lanes=lanes):
+                new_elems = self._register_pairs(refs, tmps)
+            self._pre_pairs += lanes
+            self._pre_seconds += self._timings["preprocess"] - pre_before
+        self._store.append_chunk(chunk)
+        return new_elems
+
+    def _fold_chunk_chips(self, chunk) -> List[RegElement]:
+        """Function A across the session's chips.
+
+        The chunk is split by frame, ``per`` consecutive frames to a chip
+        (the last chip's tail padded with copies of the chunk's last
+        frame), and each chip registers the pairs whose later frame it
+        holds.  A chip's first pair needs the frame before its first: the
+        last frame of the chip before, or for chip 0 the previous chunk's
+        last frame, copied in as a *halo* (a series' first chunk has no
+        pair at position 0; chip 0 registers its first frame against
+        itself there, and the result is dropped).  Frame 0 is copied to
+        every chip once per session, for function B.  The frames join the
+        store on their chips before function A runs.
+        """
+        chips, store = self._chips, self._store
+        d, n, base = len(chips), int(chunk.shape[0]), store.n
+        per = -(-n // d)
+        frame_bytes = int(chunk.nbytes) // n
+        laid = (n == per * d and isinstance(chunk, jax.Array)
+                and chunk.sharding.is_equivalent_to(self._chip_sharding,
+                                                    chunk.ndim))
+        # Halo of chip c: a global frame index, or None where the chip's
+        # own first frame stands in (no predecessor, or only padding).
+        halo_of = [base - 1 if base else None] + [
+            base + c * per - 1 if c * per < n else None for c in range(1, d)
+        ]
+        copies = sum(1 for c, g in enumerate(halo_of) if g is not None and (
+            c > 0 or not store.resident(g, chips[0])))
+        copies += (d - 1 if base == 0 else
+                   sum(not store.resident(0, dev) for dev in chips))
+        nbytes = (0 if laid else per * d * frame_bytes) + copies * frame_bytes
+        with self._stage("ingest", "frames.place", bytes=nbytes):
+            if not laid:
+                if per * d > n:
+                    pad = jnp.broadcast_to(chunk[-1:],
+                                           (per * d - n,) + chunk.shape[1:])
+                    chunk = jnp.concatenate([chunk, pad], axis=0)
+                chunk = jax.device_put(chunk, self._chip_sharding)
+            rows = _shard_rows(chunk)
+            store.append_frames([rows[p // per][p % per] for p in range(n)])
+            for c, g in enumerate(halo_of):
+                if g is not None:
+                    store.place(g, chips[c])
+            for dev in chips:
+                store.place(0, dev)
+            halo = jax.make_array_from_single_device_arrays(
+                (d,) + tuple(chunk.shape[1:]), self._chip_sharding,
+                [(rows[c][0] if g is None else store.on(g, chips[c])[0])[None]
+                 for c, g in enumerate(halo_of)],
+            )
+            jax.block_until_ready(halo)
+        self._placed_bytes += nbytes
+        first = 1 if base == 0 else 0
+        lanes = n - first
+        if not lanes:
+            return []
+        pre_before = self._timings["preprocess"]
+        with self._stage("preprocess", "fn_a", lanes=lanes):
+            new_elems = self._register_pairs_chips(halo, chunk, base, n, per)
+        self._pre_pairs += lanes
+        self._pre_seconds += self._timings["preprocess"] - pre_before
+        return new_elems
+
+    def _register_pairs_chips(self, halo, chunk, base: int, n: int,
+                              per: int) -> List[RegElement]:
+        """The sharded function-A program on a laid-out chunk: elements
+        ``(base + p - 1, base + p)`` on the chip of frame ``p``.  Records
+        iteration counts and, from the same fetch, one ``repro.fn_a.shard``
+        counter per chip and the ``repro.fn_a.lanes`` counter."""
+        reg_cfg = self.cfg.registration
+        hw = tuple(chunk.shape[1:])
+        prog = get_compile_cache().get_compiled(
+            ("pair_shards", register_pair, int(chunk.shape[0]), hw,
+             str(chunk.dtype), reg_cfg, self._chips),
+            lambda: _fn_a_shards(self._chip_sharding.mesh, reg_cfg),
+            lower_args=(halo, chunk),
+            counters=self._compile,
+        )
+        res = prog(halo, chunk)
+        jax.block_until_ready(res.deformation)
+        chip_defs = [dict(zip(res.deformation, leaves)) for leaves in zip(
+            *(_shard_rows(a) for a in res.deformation.values()))]
+        first = 1 if base == 0 else 0
+        new_elems = [
+            RegElement(
+                jax.tree.map(lambda a, j=p % per: a[j], chip_defs[p // per]),
+                base + p - 1, base + p,
+            )
+            for p in range(first, n)
+        ]
+        cohorts = [(c * per, (c + 1) * per) for c in range(len(self._chips))]
+        self._note_pair_work(res, hw, first, n, cohorts)
+        for c, (lo, hi) in enumerate(cohorts):
+            with spans.span("fn_a.shard", chip=c,
+                            pairs=max(0, min(hi, n) - max(lo, first))):
+                pass
+        return new_elems
 
     def _register_pairs(self, refs, tmps) -> List[RegElement]:
         """Function A on the batch of pairs ``(refs[i], tmps[i])``: the new
@@ -476,23 +677,37 @@ class SeriesSession:
         res = pair_fn(refs, tmps)
         jax.block_until_ready(res.deformation)
         first = self._store.n - 1 if self._store.n else 0
+        lanes = int(refs.shape[0])
         new_elems = [
             RegElement(
                 jax.tree.map(lambda a, i=i: a[i], res.deformation),
                 first + i, first + i + 1,
             )
-            for i in range(int(refs.shape[0]))
+            for i in range(lanes)
         ]
+        self._note_pair_work(res, tuple(refs.shape[1:]), 0, lanes,
+                             [(0, lanes)])
+        return new_elems
+
+    def _note_pair_work(self, res, hw, first: int, n: int, cohorts) -> None:
+        """Function A's iteration counts for the batch's pairs ``first``
+        to ``n - 1``, and the ``repro.fn_a.lanes`` counter over its
+        ``cohorts``: the ``(lo, hi)`` slices of the batch that each run
+        one vmapped loop (the whole batch on one device, a chip's block
+        across chips)."""
         iters, level_iters = jax.device_get(
             (res.iterations, res.level_iterations)
         )
-        self._pair_iters.extend(int(v) for v in iters)
-        if level_iters is not None:
-            useful, issued = lane_work(level_iters, tuple(refs.shape[1:]))
-            with spans.span("fn_a.lanes", lanes=len(new_elems),
-                            useful=useful, issued=issued):
-                pass
-        return new_elems
+        self._pair_iters.extend(int(v) for v in iters[first:n])
+        if level_iters is None:
+            return
+        useful = issued = 0
+        for lo, hi in cohorts:
+            u, i = lane_work(level_iters[lo:hi], hw)
+            useful, issued = useful + u, issued + i
+        with spans.span("fn_a.lanes", lanes=n - first, useful=useful,
+                        issued=issued):
+            pass
 
     @contextlib.contextmanager
     def _stage(self, stage: str, name: str, **stats) -> Iterator[None]:
@@ -539,13 +754,18 @@ class SeriesSession:
                 - ops_before,
         ))
 
+    def _gathered(self, tree):
+        """``tree`` on the session's first chip, where deformations that
+        several chips computed are stacked (as is on one device)."""
+        return jax.device_put(tree, self._chips[0]) if self._chips else tree
+
     def _compose_suffix(self, new_elems, seed) -> List[RegElement]:
         """refine=False: exactly-associative pure composition, vectorized —
         one batched engine scan over the chunk, one broadcast seed fold."""
         cfg = self.cfg
         batched = jax.tree.map(
             lambda *ts: jnp.stack(ts, axis=0),
-            *[e.deformation for e in new_elems],
+            *self._gathered([e.deformation for e in new_elems]),
         )
         scanned = engine_scan(
             compose_batched,
@@ -557,7 +777,7 @@ class SeriesSession:
             mesh=self._mesh,
         )
         if seed is not None:
-            sd = seed.deformation
+            sd = self._gathered(seed.deformation)
             scanned = jax.vmap(lambda d: compose(sd, d))(scanned)
         jax.block_until_ready(scanned)
         base_k = len(self._elements) + 1
@@ -612,7 +832,8 @@ class SeriesSession:
                     )
                 with spans.span("scan.plan", backend=d.backend,
                                 workers=worker_budget(workers, concurrency),
-                                elements=n_new):
+                                elements=n_new,
+                                chips=len(self._chips) if self._chips else 1):
                     pass
                 # Execute exactly what the dispatcher decided (its circuit,
                 # segment and thread counts — unless the config pins them).
@@ -645,6 +866,7 @@ class SeriesSession:
             from repro.core.engine import hierarchical
 
             self._scan_stats = hierarchical.last_stats
+            self._steals += self._scan_stats.total_inter_segment_steals()
         elif backend_used == "sharded":
             from repro.core.engine import sharded
 
@@ -670,9 +892,9 @@ class SeriesSession:
                 f"register_series needs >= 2 frames, got {self._store.n}"
             )
         with self._stage("compose", "result"):
-            all_defs = [identity_deformation()] + [
+            all_defs = self._gathered([identity_deformation()] + [
                 e.deformation for e in self._elements
-            ]
+            ])
             deformations = jax.tree.map(
                 lambda *ts: jnp.stack([jnp.asarray(t) for t in ts], axis=0),
                 *all_defs,
@@ -719,7 +941,7 @@ class SeriesSession:
         m = self._store.n
         cum = jax.tree.map(
             lambda *ts: jnp.stack([jnp.asarray(t) for t in ts], axis=0),
-            *[e.deformation for e in self._elements],
+            *self._gathered([e.deformation for e in self._elements]),
         )
         state = {
             "cum": cum,

@@ -88,6 +88,24 @@ def test_op_concurrency_caps_element_workers(op, c):
         assert d.num_threads <= c
 
 
+@pytest.mark.parametrize("n,imbalance,backend,cross", [
+    (32, None, "hierarchical", True),
+    (32, 1.2, "hierarchical", False),
+    (31, 3.0, "hierarchical", True),
+    (7, None, "worksteal", None),
+], ids=["unobserved", "balanced", "imbalanced", "too_short"])
+def test_several_devices_get_one_worker_each(n, imbalance, backend, cross):
+    """An expensive operator whose data lies on four devices runs the
+    hierarchical scan with one segment and one worker per device, stealing
+    across segments unless the observed imbalance is low; fewer than two
+    elements a device fall back to flat stealing."""
+    d = dispatch(n, domain="element", op_cost=0.1, workers=30,
+                 op_imbalance=imbalance, op_concurrency=4)
+    assert d.backend == backend
+    if backend == "hierarchical":
+        assert (d.num_segments, d.num_threads, d.cross_steal) == (4, 1, cross)
+
+
 def test_one_executor_operator_runs_the_seeded_chain():
     """Through ``scan``: an expensive operator whose applications cannot run
     at once folds a seeded suffix in one application per element."""

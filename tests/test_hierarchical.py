@@ -456,3 +456,57 @@ def test_register_series_cross_steal_knob_and_report():
     )
     assert res.scan_stats is not None and res.scan_stats.cross_steal
     assert "cross-segment steals:" in res.report()
+
+
+class _Placed:
+    """Interval sums whose elements live on executors: ``op_homes`` says
+    where, and ``op_worker(w)`` applies the operator as executor ``w``'s
+    worker.  Records ``(worker, right operand)`` per application."""
+
+    def __init__(self, homes):
+        self.homes = homes
+        self.calls = []
+
+    def op_homes(self, xs):
+        return [self.homes[x[0]] for x in xs]
+
+    def op_worker(self, w):
+        return lambda a, b: self._apply(a, b, w)
+
+    def __call__(self, a, b):
+        return self._apply(a, b, None)
+
+    def _apply(self, a, b, worker):
+        assert a[1] + 1 == b[0], (a, b)
+        self.calls.append((worker, b))
+        return (a[0], b[1], a[2] + b[2])
+
+
+@pytest.mark.parametrize("seed", [None, (-1, -1, 100)],
+                         ids=["unseeded", "seeded"])
+def test_segments_follow_element_homes(seed):
+    """Each run of one executor is a segment (whatever ``num_segments``
+    says), its applications go through that executor's worker, and a seed
+    makes phase 2 a chain of S - 1 applications."""
+    from repro.core.engine import hierarchical as hier
+    from repro.core.engine.plan import get_plan
+
+    homes = [0, 0, 0, 1, 1, 1, 2, 2, 3, 3]
+    xs = [(j, j, j + 1) for j in range(len(homes))]
+    op = _Placed(homes)
+    ys, total = hier.exec_hierarchical(op, None, xs, num_segments=2,
+                                  num_threads=1, seed=seed, cross_steal=False)
+    st = hier.last_stats
+    assert st.segment_bounds == [(0, 2), (3, 5), (6, 7), (8, 9)]
+    base = 0 if seed is None else 100
+    assert [y[2] for y in ys] == list(base + np.cumsum(np.arange(1, 11)))
+    assert total == ys[-1]
+    assert st.total_ops == len(op.calls)
+    for worker, b in op.calls:
+        if worker is not None and b[1] == b[0]:    # a single element
+            assert homes[b[0]] == worker
+    # Phase 2: the seeded chain takes S - 1 = 3 applications, outside any
+    # worker; unseeded, the circuit over 4 totals takes its work.
+    outside = sum(w is None for w, _ in op.calls)
+    assert outside == (3 if seed is not None else
+                       get_plan("ladner_fischer", 4).work())
