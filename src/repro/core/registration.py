@@ -88,45 +88,50 @@ def _minimize_level(
 ) -> Tuple[Deformation, jax.Array, jax.Array]:
     """Gradient flow on one pyramid level with data-dependent stopping.
 
+    A step is one forward pass (warp + NCC) and one backward pass: the
+    loop carries the loss and its gradient at ``d``, and one
+    ``value_and_grad`` at the new point gives both the stopping test's
+    loss and the next step's gradient.
+
     The loop is *per-lane frozen*: under ``vmap`` a batched ``while_loop``
-    keeps executing the body until every lane converges, and an unguarded
-    body would keep stepping lanes that already met the tolerance — making
-    a pair's result depend on which batch it was registered with (chunked
-    streaming ingest would diverge from batch ingest) and making the
-    per-lane iteration count read the cohort maximum instead of the
-    lane's own cost.  ``active`` masks the update, so every lane follows
-    exactly its solo trajectory regardless of cohort.
+    keeps executing the body until every lane converges, and a lane that
+    already met the tolerance must not step on — that would make a pair's
+    result depend on which batch it was registered with (chunked streaming
+    ingest would diverge from batch ingest) and make the per-lane
+    iteration count read the cohort maximum instead of the lane's own
+    cost.  ``act`` masks the update, the carried gradient included, so
+    every lane follows exactly its solo trajectory regardless of cohort
+    (JAX's batching rule for a batched predicate selects the carry per
+    lane as well).
     """
 
-    loss = lambda d: ncc_distance(ref, tmpl, d)
-    grad = jax.grad(loss)
+    loss_and_grad = jax.value_and_grad(lambda d: ncc_distance(ref, tmpl, d))
     ang_step = _angle_step(cfg, ref.shape)
 
     def active_of(state):
-        _, prev, cur, it = state
+        _, _, prev, cur, it = state
         return jnp.logical_and(it < cfg.max_iters, jnp.abs(prev - cur) > cfg.tol)
 
     def body(state):
-        d, prev, cur, it = state
+        d, g, prev, cur, it = state
         act = active_of(state)
-        g = grad(d)
         d_new = {
             "angle": d["angle"] - ang_step * g["angle"],
             "shift": d["shift"] - cfg.lr_shift * g["shift"],
         }
-        new = loss(d_new)
+        new, g_new = loss_and_grad(d_new)
         keep = lambda nv, ov: jnp.where(act, nv, ov)
         return (
             jax.tree.map(keep, d_new, d),
+            jax.tree.map(keep, g_new, g),
             keep(cur, prev),
             keep(new, cur),
             it + act.astype(jnp.int32),
         )
 
-    d0 = init
-    l0 = loss(d0)
-    state = (d0, l0 + 1.0, l0, jnp.zeros((), jnp.int32))
-    d, _, final, iters = jax.lax.while_loop(active_of, body, state)
+    l0, g0 = loss_and_grad(init)
+    state = (init, g0, l0 + 1.0, l0, jnp.zeros((), jnp.int32))
+    d, _, _, final, iters = jax.lax.while_loop(active_of, body, state)
     return d, final, iters
 
 

@@ -1,5 +1,8 @@
 """Image registration: deformations, function A/B, series scan (paper §2.3/§3)."""
 
+import dataclasses
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,11 +19,14 @@ from repro.core.deformation import (
     inverse,
     make_deformation,
     ncc,
+    ncc_distance,
     warp,
 )
 from repro.core.registration import (
     RegistrationConfig,
     SeriesRegistrar,
+    _angle_step,
+    _pyramid,
     register_pair,
 )
 from repro.core.scan import prefix_scan
@@ -247,3 +253,89 @@ def test_element_cost_estimates_preserve_straggler_signal():
     est = op.element_cost_estimates(8)
     assert abs(est[0] - est[1]) < 1e-9
     assert est[0] > est[2]
+
+
+# Above the float32 summation noise of the loss near a minimum: at the
+# default 1e-7 two programs that round the loss differently may stop
+# steps apart (a batched and a solo one, or a fused and a separate forward
+# pass), so per-step agreement is checked where the stop follows the
+# trajectory rather than the rounding.
+TRAJ_CFG = dataclasses.replace(CFG, tol=1e-5)
+
+
+def _two_pass_minimize_level(ref, tmpl, init, cfg):
+    """Reference: the minimiser step as ``grad`` at ``d`` then a plain
+    ``loss`` at ``d_new``, two forward passes and one backward a step."""
+    loss = lambda d: ncc_distance(ref, tmpl, d)
+    grad = jax.grad(loss)
+    ang_step = _angle_step(cfg, ref.shape)
+
+    def active_of(state):
+        _, prev, cur, it = state
+        return jnp.logical_and(it < cfg.max_iters, jnp.abs(prev - cur) > cfg.tol)
+
+    def body(state):
+        d, prev, cur, it = state
+        act = active_of(state)
+        g = grad(d)
+        d_new = {
+            "angle": d["angle"] - ang_step * g["angle"],
+            "shift": d["shift"] - cfg.lr_shift * g["shift"],
+        }
+        new = loss(d_new)
+        keep = lambda nv, ov: jnp.where(act, nv, ov)
+        return (jax.tree.map(keep, d_new, d), keep(cur, prev), keep(new, cur),
+                it + act.astype(jnp.int32))
+
+    l0 = loss(init)
+    state = (init, l0 + 1.0, l0, jnp.zeros((), jnp.int32))
+    d, _, final, iters = jax.lax.while_loop(active_of, body, state)
+    return d, final, iters
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _two_pass_register_pair(ref, tmpl, cfg):
+    """``register_pair`` from the identity with the two-pass step."""
+    refs, tmps = _pyramid(ref, cfg.levels), _pyramid(tmpl, cfg.levels)
+    d = {"angle": jnp.zeros(()), "shift": jnp.zeros((2,))}
+    level_iters = []
+    for lvl, (r, t) in enumerate(zip(refs, tmps)):
+        d, _, iters = _two_pass_minimize_level(r, t, d, cfg)
+        level_iters.append(iters)
+        if lvl != len(refs) - 1:
+            d = {"angle": d["angle"], "shift": d["shift"] * 2.0}
+    return d, jnp.stack(level_iters)
+
+
+@pytest.mark.parametrize("size", [(96, 96), (480, 464)])
+def test_one_pass_step_follows_two_pass_trajectory(size):
+    """Carrying value_and_grad through the loop changes the cost of a step,
+    not the step: same deformation, same iterations per level."""
+    frames, _ = make_series(jax.random.PRNGKey(11), 2, size=size, noise=0.15)
+    res = register_pair(frames[0], frames[1], None, TRAJ_CFG)
+    d_ref, its_ref = _two_pass_register_pair(frames[0], frames[1], TRAJ_CFG)
+    np.testing.assert_allclose(np.asarray(res.deformation["shift"]),
+                               np.asarray(d_ref["shift"]), atol=1e-4)
+    np.testing.assert_allclose(float(res.deformation["angle"]),
+                               float(d_ref["angle"]), atol=1e-6)
+    its, its_ref = np.asarray(res.level_iterations), np.asarray(its_ref)
+    assert np.abs(its - its_ref).max() <= 2, (its, its_ref)
+    assert its.min() > 1
+
+
+def test_vmapped_lanes_keep_their_solo_result():
+    """The per-lane freeze: a lane that converges first stops stepping, so
+    under vmap each pair keeps its own iterations and deformation."""
+    frames, _ = make_series(jax.random.PRNGKey(5), 4, size=96, noise=0.2)
+    refs, tmps = frames[:-1], frames[1:]
+    batch = jax.vmap(lambda r, t: register_pair(r, t, None, TRAJ_CFG))(refs, tmps)
+    solo = [register_pair(refs[i], tmps[i], None, TRAJ_CFG) for i in range(3)]
+    solo_its = np.stack([np.asarray(s.level_iterations) for s in solo])
+    # Pairs of different difficulty: a shared count would read the maximum.
+    assert len({tuple(r) for r in solo_its}) == 3, solo_its
+    np.testing.assert_array_equal(np.asarray(batch.level_iterations), solo_its)
+    for i, s in enumerate(solo):
+        np.testing.assert_allclose(np.asarray(batch.deformation["shift"][i]),
+                                   np.asarray(s.deformation["shift"]), atol=1e-5)
+        np.testing.assert_allclose(float(batch.deformation["angle"][i]),
+                                   float(s.deformation["angle"]), atol=1e-7)
